@@ -38,11 +38,10 @@ def run() -> list[tuple[str, float, str]]:
     t_plain = _time(plain, q, k, v)
     t_blocked = _time(blocked, q, k, v)
 
-    from repro.analysis.hlo import cost_dict
-    bytes_plain = float(cost_dict(jax.jit(plain).lower(q, k, v).compile())
-                        .get("bytes accessed", 0))
-    bytes_blocked = float(cost_dict(jax.jit(blocked).lower(q, k, v)
-                                    .compile()).get("bytes accessed", 0))
+    bytes_plain = float(jax.jit(plain).lower(q, k, v).compile()
+                        .cost_analysis().get("bytes accessed", 0))
+    bytes_blocked = float(jax.jit(blocked).lower(q, k, v).compile()
+                          .cost_analysis().get("bytes accessed", 0))
     return [
         ("attention_plain_2k", t_plain * 1e6,
          f"bytes={bytes_plain/2**20:.0f}MiB"),

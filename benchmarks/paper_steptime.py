@@ -53,8 +53,7 @@ def _timed_step(cfg, batch: int, mixed: bool, iters: int = 4):
 
     # bytes from the compiled artifact (TPU roofline proxy)
     comp = step.lower(params, opt_state, images, labels).compile()
-    from repro.analysis.hlo import cost_dict
-    byts = float(cost_dict(comp).get("bytes accessed", 0.0))
+    byts = float(comp.cost_analysis().get("bytes accessed", 0.0))
     return wall, byts
 
 

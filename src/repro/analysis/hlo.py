@@ -139,20 +139,9 @@ def model_flops_per_step(n_params: int, tokens: int, kind: str = "train",
     return mult * n * tokens
 
 
-def cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    Old jax wraps the properties dict in a one-element list.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def roofline_from_compiled(compiled, chips: int,
                            model_flops: Optional[float] = None) -> Roofline:
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

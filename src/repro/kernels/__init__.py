@@ -3,7 +3,7 @@
 - ``flash_attention``   — blocked prefill attention (fp32 streaming state)
 - ``decode_attention``  — one query vs a *contiguous* (B, S, K, D) cache
 - ``paged_attention``   — page-table-indexed serving attention over the
-  shared (P, page_size, K, D) pools: scalar-prefetch page tables, no
+  shared head-major (P, K, page_size, D) pools: scalar-prefetch page tables, no
   gathered dense copy, covers decode AND chunked-prefill queries
 - ``rmsnorm`` / ``unscale_finite`` — fused MPX precision primitives
 - ``ref``               — pure-jnp oracles every kernel is tested against

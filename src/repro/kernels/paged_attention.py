@@ -3,35 +3,40 @@ with in-kernel dequantization of sub-bf16 (int8 / fp8) page pools.
 
 The serving hot path is HBM-bandwidth-bound, and the paged KV layout
 (``repro.serve.cache``) stores every slot's cache as fixed-size pages of a
-shared ``(P, page_size, K, D)`` pool addressed through a per-slot page
-table.  The gather-based path first materializes each slot's *entire
+shared head-major ``(P, K, page_size, D)`` pool addressed through a
+per-slot page table.  The gather-based path first materializes each slot's *entire
 padded* prefix — ``(B, Pmax*page_size, K, D)`` — as a dense copy, per
 layer, per tick, garbage sentinel pages included.  This kernel instead
 walks each slot's page table directly: the page table and per-slot chunk
 ``start``/``valid`` counts are scalar-prefetch (SMEM) operands, and the
 K/V block index maps resolve logical page ``i`` -> physical page
 ``table[b, i]`` in the pool, so the DMA engine streams exactly the pages
-the scheduler allocated, exactly once.  Pages past a slot's length
+the scheduler allocated, exactly once.  Head-major pools make each K/V
+block one (page, kv head) ``(page_size, D)`` tile whose minor two dims
+are the pool's own: the TPU compiler refuses a token-major
+``(P, page_size, K, D)`` pool, whose block would squeeze the kv-head axis
+into the second-minor position.  Pages past a slot's length
 re-issue the previous block index (the pipeline elides the refetch) and
 their compute is predicated off — unallocated pages are never read.
 
-Quantized pools (``repro.quant``) add two more *blocked* operands: the
-``(P, K)`` fp32 amax-scale sidecars for K and V.  Each sub-page's scale
-is a ``(1, 1)`` block whose index map resolves the SAME logical page ->
-physical page mapping as that sub-page's value block (one shared
-``_phys_page`` helper, so the value and its scale can never point at
-different pages), and each K/V block is dequantized *in VMEM* —
+Quantized pools (``repro.quant``) add two more VMEM operands: the
+``(P, K)`` fp32 amax-scale sidecars for K and V, each handed to the
+kernel as one resident ``(1, P)`` row per kv head (a ``(1, 1)`` block
+per page is refused by the TPU compiler).  The row's block index changes
+only with the grid row, so it is fetched once per (slot, kv head); each
+sub-page's scale is selected from it in VMEM by the SAME logical page ->
+physical page rule as that sub-page's value block (one shared
+``_phys_page`` helper, so a value and its scale can never point at
+different pages).  Each K/V block is dequantized *in VMEM* —
 ``block.astype(f32) * scale``, cast to the query dtype — before the
 score/output matmuls.  The pool is streamed at 1 byte/element and the
-dense bf16 view of the cache never exists anywhere: not in HBM (the
-gather copy PR 3 removed) and not as a pool-shaped intermediate
-(dequant happens block-by-block in registers).  The sidecars ride
-blocked VMEM rather than scalar-prefetch SMEM deliberately: SMEM is a
-few KB per core and the sidecar grows with the *pool* (``P * K`` fp32
-each), so a production-sized pool would blow the scalar-prefetch budget
-— only the O(B * Pmax) page table and the (B,) start/valid vectors
-belong there.  Sidecar HBM cost stays ~``page_size * head_dim / 2``
-times below the pools it describes.
+dense bf16 view of the cache never exists anywhere, neither in HBM nor as
+a pool-shaped intermediate.  The sidecars stay out of scalar-prefetch
+SMEM deliberately: SMEM is small and the sidecar grows with the *pool*
+(``P * K`` fp32 each) — only the O(B * Pmax) page table and the (B,)
+start/valid vectors belong there.  The VMEM rows cost ``4 * P`` bytes
+each (padded to 8 sublanes), and selecting a scale is an O(P) masked
+sum per sub-page.
 
 Queries cover every ``serve_forward`` step shape, not just single-token
 decode: q is ``(B, C, H, D)`` where ``C = 1`` is decode and ``C > 1`` a
@@ -73,8 +78,8 @@ def _phys_page(table_ref, start_ref, valid_ref, b, logical, *,
     """Logical page of slot ``b`` -> clamped physical pool page.
 
     THE logical->physical rule, shared by the K/V value block index maps
-    and the scale block index maps (a value and its scale must always
-    resolve to the same page): pages past the slot's last used page
+    and the kernel body's scale selection (a value and its scale must
+    always resolve to the same page): pages past the slot's last used page
     re-issue the last used index, the sentinel is clamped into range —
     compute for either case is predicated off by the kernel body.
     """
@@ -91,9 +96,8 @@ def _paged_kernel(table_ref, start_ref, valid_ref, *refs,
     v_refs = refs[1 + ppb:1 + 2 * ppb]
     refs = refs[1 + 2 * ppb:]
     if quantized:
-        ks_refs = refs[:ppb]                  # (1, 1) scale per sub-page
-        vs_refs = refs[ppb:2 * ppb]
-        refs = refs[2 * ppb:]
+        scale_refs = refs[:2]                 # (1, P) this kv head's scales
+        refs = refs[2:]
     o_ref = refs[0]
     m_scr, l_scr, acc_scr = refs[1:]
     i = pl.program_id(1)
@@ -110,31 +114,35 @@ def _paged_kernel(table_ref, start_ref, valid_ref, *refs,
     length = start + valid_ref[b]        # cached tokens incl. this chunk
     block_lo = i * ppb * page_size
 
-    def _block(refs_j, scale_ref_j):
-        """One sub-page's (page_size, D) block, dequantized in VMEM with
-        its own page's (1, 1) sidecar scale block (same index map)."""
+    def _block(refs_j, scale_ref, j):
+        """Sub-page ``j``'s (page_size, D) block, dequantized in VMEM with
+        its own page's scale, picked out of the resident scale row by the
+        same logical->physical rule as the block's index map."""
         blk = refs_j[...]
         if not quantized:
             return blk
-        return (blk.astype(jnp.float32) *
-                scale_ref_j[0, 0]).astype(q_ref.dtype)
+        phys = _phys_page(table_ref, start_ref, valid_ref, b, i * ppb + j,
+                          page_size=page_size, n_pages=scale_ref.shape[1])
+        row = scale_ref[...]                                    # (1, P)
+        hit = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) == phys
+        sc = jnp.sum(jnp.where(hit, row, 0.0), axis=1, keepdims=True)
+        return (blk.astype(jnp.float32) * sc).astype(q_ref.dtype)
 
     @pl.when(block_lo < length)
     def _body():
         q = q_ref[...]                                    # (C*G, D) bf16
+        ks_ref, vs_ref = scale_refs if quantized else (None, None)
         if ppb == 1:
-            k = _block(k_refs[0], ks_refs[0] if quantized else None)
-            v = _block(v_refs[0], vs_refs[0] if quantized else None)
+            k = _block(k_refs[0], ks_ref, 0)
+            v = _block(v_refs[0], vs_ref, 0)
         else:
             # ppb logical pages, each DMA'd from its own physical page,
             # dequantized with its own scale, concatenated in VMEM into
             # one (ppb*ps, D) matmul operand
             k = jnp.concatenate(
-                [_block(r, ks_refs[j] if quantized else None)
-                 for j, r in enumerate(k_refs)], axis=0)
+                [_block(r, ks_ref, j) for j, r in enumerate(k_refs)], axis=0)
             v = jnp.concatenate(
-                [_block(r, vs_refs[j] if quantized else None)
-                 for j, r in enumerate(v_refs)], axis=0)
+                [_block(r, vs_ref, j) for j, r in enumerate(v_refs)], axis=0)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (C*G, ppb*ps) f32
@@ -173,8 +181,8 @@ def paged_attention(q, k_pages, v_pages, page_table, start, valid, *,
 
     q (B, C, H, D) — one serving chunk per slot (C = 1 decode, C > 1
     prefill / speculative window / mixed); k_pages / v_pages
-    (P, page_size, K, D) — the shared pools, chunk K/V already scattered
-    in (``paged_write`` / ``quantized_pool_write`` runs first);
+    (P, K, page_size, D) — the shared head-major pools, chunk K/V already
+    scattered in (``paged_write`` / ``quantized_pool_write`` runs first);
     page_table (B, Pmax) int32 logical->physical map whose unallocated
     entries hold the sentinel ``P``; start (B,) absolute position of
     each slot's chunk; valid (B,) real tokens in the chunk (0 = idle
@@ -184,11 +192,10 @@ def paged_attention(q, k_pages, v_pages, page_table, start, valid, *,
     the pools hold int8 or fp8 (``repro.quant`` formats) and every K/V
     block is dequantized in VMEM — ``block * scales[phys, kv_head]`` —
     before its matmul.  Both must be given together; without them the
-    pools are attended to as-is (the bf16 baseline).  Each sub-page's
-    scale arrives as its own (1, 1) block through the same
-    logical->physical index map as the sub-page's values (blocked VMEM,
-    not scalar-prefetch SMEM — the sidecar scales with the pool and
-    would not fit the SMEM budget at production pool sizes).
+    pools are attended to as-is (the bf16 baseline).  Each sidecar
+    reaches the kernel as a resident (1, P) VMEM row per kv head, and
+    each sub-page's scale is selected from it by the same
+    logical->physical rule as the sub-page's values.
 
     Query ``ci`` of slot ``b`` attends causally to cache positions
     ``<= start[b] + ci``; padding positions (``ci >= valid[b]``) and idle
@@ -199,7 +206,7 @@ def paged_attention(q, k_pages, v_pages, page_table, start, valid, *,
     softcaps are the caller's fallback path.
     """
     b, c, h, d = q.shape
-    n_pages, page_size, kv, _ = k_pages.shape
+    n_pages, kv, page_size, _ = k_pages.shape
     if h % kv:
         raise ValueError(f"n_kv_heads {kv} must divide n_heads {h}")
     if pages_per_block < 1:
@@ -234,25 +241,26 @@ def paged_attention(q, k_pages, v_pages, page_table, start, valid, *,
     def page_index(j):
         def index_map(bk, i, *scalar_refs):
             phys = sub_page_phys(bk, i, j, *scalar_refs)
-            return (phys, 0, bk % kv, 0)
+            return (phys, bk % kv, 0, 0)
         return index_map
 
-    def scale_index(j):
-        def index_map(bk, i, *scalar_refs):
-            phys = sub_page_phys(bk, i, j, *scalar_refs)
-            return (phys, bk % kv)
-        return index_map
-
-    kv_specs = [pl.BlockSpec((None, page_size, None, d), page_index(j))
+    # head-major pools: one (page_size, D) block per (page, kv head).  Its
+    # minor two dims are the array's own, so the TPU tiling rules accept
+    # it (compiled for v5e at page_size 16: tests/test_tpu_compile.py)
+    kv_specs = [pl.BlockSpec((None, None, page_size, d), page_index(j))
                 for j in range(ppb)]
-    sc_specs = [pl.BlockSpec((1, 1), scale_index(j)) for j in range(ppb)]
     inputs = [qf] + [k_pages] * ppb + [v_pages] * ppb
     in_specs = ([pl.BlockSpec((None, cg, d), lambda bk, i, *_: (bk, 0, 0))]
                 + kv_specs + kv_specs)
     if quantized:
-        inputs += ([jnp.asarray(k_scales, jnp.float32)] * ppb
-                   + [jnp.asarray(v_scales, jnp.float32)] * ppb)
-        in_specs += sc_specs + sc_specs
+        # (P, K) sidecar -> one resident (1, P) row per kv head; its block
+        # index only changes with the grid row, so it is fetched once per
+        # (slot, kv head) and each sub-page's scale is selected in VMEM
+        sc_spec = pl.BlockSpec((None, 1, n_pages),
+                               lambda bk, i, *_: (bk % kv, 0, 0))
+        inputs += [jnp.asarray(sc, jnp.float32).T.reshape(kv, 1, n_pages)
+                   for sc in (k_scales, v_scales)]
+        in_specs += [sc_spec, sc_spec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b * kv, -(-pmax // ppb)),

@@ -50,7 +50,8 @@ def decode_attention_ref(q, k, v, lengths) -> jnp.ndarray:
     s, kv = k.shape[1], k.shape[2]
     ke = jnp.repeat(k, h // kv, axis=2)
     ve = jnp.repeat(v, h // kv, axis=2)
-    scores = jnp.einsum("bhd,bshd->bhs", q, ke).astype(jnp.float32)
+    scores = jnp.einsum("bhd,bshd->bhs", q, ke,
+                        preferred_element_type=jnp.float32)
     scores = scores / math.sqrt(d)
     lengths_b = jnp.asarray(lengths, jnp.int32).reshape(-1, 1, 1)
     scores = jnp.where(jnp.arange(s)[None, None, :] < lengths_b,
@@ -64,7 +65,7 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, start, valid
     """Ragged paged-attention oracle (gathers the pool; the kernel doesn't).
 
     q (B, C, H, D) — one serving chunk per slot; k_pages / v_pages
-    (P, page_size, K, D) shared pools; page_table (B, Pmax) int32 with the
+    (P, K, page_size, D) shared head-major pools; page_table (B, Pmax) int32 with the
     sentinel ``P`` in unallocated entries; start / valid (B,) chunk
     position and real-token count.  Query ``ci`` attends causally to
     cache positions ``<= start + ci``; padding positions (``ci >= valid``)
@@ -74,12 +75,12 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, start, valid
     ground truth the page-table-walking kernel is tested against.
     """
     b, c, h, d = q.shape
-    n_pages, ps, kv, _ = k_pages.shape
+    n_pages, kv, ps, _ = k_pages.shape
     pmax = page_table.shape[1]
     s_max = pmax * ps
     tbl = jnp.clip(jnp.asarray(page_table, jnp.int32), 0, n_pages - 1)
-    k = k_pages[tbl].reshape(b, s_max, kv, d)
-    v = v_pages[tbl].reshape(b, s_max, kv, d)
+    k = k_pages[tbl].swapaxes(2, 3).reshape(b, s_max, kv, d)
+    v = v_pages[tbl].swapaxes(2, 3).reshape(b, s_max, kv, d)
     ke = jnp.repeat(k, h // kv, axis=2)
     ve = jnp.repeat(v, h // kv, axis=2)
     start = jnp.asarray(start, jnp.int32)
@@ -89,7 +90,8 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, start, valid
     # softmax over NEG_INF scores) cannot pick up garbage-page values
     length = (start + valid)[:, None, None, None]
     ve = jnp.where(kpos[None, :, None, None] < length, ve, 0)
-    scores = jnp.einsum("bchd,bshd->bhcs", q, ke).astype(jnp.float32)
+    scores = jnp.einsum("bchd,bshd->bhcs", q, ke,
+                        preferred_element_type=jnp.float32)
     scores = scores / math.sqrt(d)
     qpos = start[:, None] + jnp.arange(c)[None, :]
     ok = (kpos[None, None, :] <= qpos[:, :, None]) & \
@@ -116,8 +118,8 @@ def quantized_paged_attention_ref(q, k_pages, v_pages, k_scales, v_scales,
     kernel exists to avoid — ground truth only.
     """
     from repro.quant.ops import dequantize
-    k = dequantize(k_pages, k_scales[:, None, :, None], q.dtype)
-    v = dequantize(v_pages, v_scales[:, None, :, None], q.dtype)
+    k = dequantize(k_pages, k_scales[:, :, None, None], q.dtype)
+    v = dequantize(v_pages, v_scales[:, :, None, None], q.dtype)
     return paged_attention_ref(q, k, v, page_table, start, valid)
 
 

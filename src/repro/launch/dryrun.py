@@ -144,7 +144,7 @@ def _compile_cell(cfg, kind: str, mesh, run: RunConfig, global_batch: int,
 
 
 def _cell_costs(compiled) -> dict:
-    cost = hlo_lib.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
             "coll": float(hlo_lib.collective_bytes(compiled.as_text()))}

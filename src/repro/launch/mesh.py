@@ -8,22 +8,13 @@ seeing one CPU device.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 tags mesh axes for explicit sharding; Auto == old default
-    from jax.sharding import AxisType
-
-    def _auto_axes(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: every axis is implicitly Auto
-    AxisType = None
-
-    def _auto_axes(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
-def make_mesh(shape, names):
-    """``jax.make_mesh`` with all axes Auto, across jax versions."""
-    return jax.make_mesh(shape, names, **_auto_axes(len(names)))
+def make_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with every axis Auto (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,7 +24,8 @@ import jax
 from repro.configs import registry
 from repro.configs.base import RunConfig
 from repro.data.pipeline import SyntheticTokens
-from repro.launch.mesh import single_device_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.optim import make_optimizer
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -55,6 +56,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--set", nargs="*", default=[], metavar="K=V")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))
@@ -66,7 +68,9 @@ def main(argv=None):
         cfg, run, optimizer, data,
         TrainerConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                       ckpt_every=args.ckpt_every, log_every=args.log_every),
-        mesh=single_device_mesh() if jax.device_count() == 1 else None)
+        # every visible device, data parallel (ZeRO-1 shards the
+        # optimizer state over the same axis)
+        mesh=make_host_mesh(data=jax.device_count()))
     trainer.fit()
 
 

@@ -355,9 +355,9 @@ def abstract_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                          n_slots: int = 1) -> PyTree:
     """Per-layer-kind state-pool stand-ins mirroring the scan/tail layout.
 
-    Attention layers get one (n_pages, page_size, K, D) pool pair each;
-    all of them share one page table (each has its own pool array), so the
-    serve scheduler allocates pages once per sequence.  A quantized
+    Attention layers get one head-major (n_pages, K, page_size, D) pool
+    pair each; all of them share one page table (each has its own pool
+    array), so the serve scheduler allocates pages once per sequence.  A quantized
     ``kv_format`` ("i8", "f8_e4m3", "f8_e3m4" — see :mod:`repro.quant`)
     stores those pools in the format's storage dtype and adds a
     (n_pages, K) fp32 amax-scale sidecar pair per layer; ``dtype`` then

@@ -49,7 +49,8 @@ def attention_spec(d_model: int, n_heads: int, n_kv_heads: int, head_dim: int,
         "wv": ParamSpec((d_model, n_kv_heads, head_dim),
                         ("embed", "kv_heads", "head_dim")),
         "wo": ParamSpec((n_heads, head_dim, d_model),
-                        ("heads", "head_dim", "embed")),
+                        ("heads", "head_dim", "embed"),
+                        fan_in=n_heads * head_dim),
     }
     if qkv_bias:
         spec["bq"] = ParamSpec((n_heads, head_dim), ("heads", "head_dim"),
@@ -267,8 +268,8 @@ def decode_step(params, cache, x, pos, *, n_heads: int, window: int,
     kx = _expand_kv(k, n_heads)
     vx = _expand_kv(v, n_heads)
     scale = 1.0 / math.sqrt(q.shape[-1])
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kx) * scale
-    scores = scores.astype(jnp.float32)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kx,
+                        preferred_element_type=jnp.float32) * scale
     if cap > 0:
         scores = cap * jnp.tanh(scores / cap)
     # stored position of each slot (rolling-buffer aware)
@@ -297,9 +298,11 @@ def decode_step(params, cache, x, pos, *, n_heads: int, window: int,
 # (B, max_seq, K, D) cache slab with a shared pool of fixed-size pages:
 # each slot owns a row of a page table mapping logical page -> physical
 # page, so HBM is committed per admitted request, not per slot capacity.
-# Token position p of slot b lives at pages[table[b, p // page_size],
-# p % page_size].  Unallocated table entries hold the sentinel ``n_pages``
-# (writes there are dropped).
+# Pools are head-major, (P, K, page_size, D): token position p of slot b,
+# kv head h lives at pages[table[b, p // page_size], h, p % page_size], so
+# one (page, head) is a contiguous (page_size, D) tile — the block shape
+# the TPU's tiling rules accept in the kernel.  Unallocated table entries
+# hold the sentinel ``n_pages`` (writes there are dropped).
 #
 # Attention reads the pool two ways.  The native path
 # (``use_kernel=True``) is the Pallas paged-attention kernel
@@ -334,7 +337,7 @@ def paged_cache_spec(n_pages: int, page_size: int, n_kv_heads: int,
 def paged_write(pages: jnp.ndarray, vals: jnp.ndarray,
                 page_table: jnp.ndarray, positions: jnp.ndarray,
                 valid: jnp.ndarray, *, page_size: int) -> jnp.ndarray:
-    """Scatter ``vals`` (B, C, K, D) into ``pages`` (P, ps, K, D).
+    """Scatter ``vals`` (B, C, K, D) into head-major ``pages`` (P, K, ps, D).
 
     ``positions`` (B, C) are absolute token positions, ``valid`` (B,) the
     number of real tokens per slot (suffix is padding).  Padding tokens and
@@ -357,12 +360,12 @@ def paged_write(pages: jnp.ndarray, vals: jnp.ndarray,
     off = positions % page_size
     ok = jnp.arange(c)[None, :] < valid[:, None]
     phys = jnp.where(ok, phys, n_pages)                      # OOB -> dropped
-    return pages.at[phys.reshape(-1), off.reshape(-1)].set(
+    return pages.at[phys.reshape(-1), :, off.reshape(-1)].set(
         vals.reshape((b * c,) + vals.shape[2:]), mode="drop")
 
 
 def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """(P, ps, K, D), (B, Pmax) -> contiguous view (B, Pmax*ps, K, D).
+    """(P, K, ps, D), (B, Pmax) -> contiguous view (B, Pmax*ps, K, D).
 
     The gather-based *fallback* layout for attention: a dense padded copy
     of every slot's prefix, sentinel entries reading clamped garbage that
@@ -370,7 +373,7 @@ def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     ``paged_attend(use_kernel=True)`` streams pages through the page
     table inside the Pallas kernel instead.
     """
-    g = pages[page_table]
+    g = pages[page_table].swapaxes(2, 3)              # (B, Pmax, ps, K, D)
     b, pmax, ps = g.shape[:3]
     return g.reshape((b, pmax * ps) + g.shape[3:])
 
@@ -382,7 +385,7 @@ def paged_gather_scales(scales: jnp.ndarray, page_table: jnp.ndarray,
     Companion to :func:`paged_gather` for quantized pools: every token of
     a page shares its page's per-head scale, so the gathered scale is
     broadcast over the ``page_size`` rows.  Fallback/oracle path only —
-    the kernel reads the (P, K) sidecar directly from SMEM.
+    the kernel selects each page's scale from a resident per-head row.
     """
     g = scales[page_table]                                # (B, Pmax, K)
     b, pmax, kv = g.shape
@@ -418,8 +421,8 @@ def paged_attend(params, pages: dict, page_table: jnp.ndarray,
     ``use_kernel=True`` runs the Pallas paged-attention kernel
     (:mod:`repro.kernels.paged_attention`) for full-attention layers: the
     page table is a scalar-prefetch operand (quantized scale sidecars
-    ride blocked VMEM through the same page index maps — they scale with
-    the pool, so SMEM is the wrong home) and the kernel's block index
+    ride VMEM as one resident row per kv head — they scale with the
+    pool, so SMEM is the wrong home) and the kernel's block index
     maps stream each slot's allocated pages directly from the shared
     pool — the gathered
     contiguous (B, Pmax*page_size, K, D) copy is never formed, for
@@ -470,8 +473,8 @@ def paged_attend(params, pages: dict, page_table: jnp.ndarray,
         kx = _expand_kv(k, n_heads)
         vx = _expand_kv(v, n_heads)
         scale = 1.0 / math.sqrt(q.shape[-1])
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kx) * scale
-        scores = scores.astype(jnp.float32)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kx,
+                            preferred_element_type=jnp.float32) * scale
         if cap > 0:
             scores = cap * jnp.tanh(scores / cap)
         idx = jnp.arange(k.shape[1])

@@ -34,13 +34,14 @@ def moe_spec(d_model: int, d_ff: int, n_experts: int, kind: str = "swiglu"):
     spec = {
         "router": ParamSpec((d_model, n_experts), ("embed", "experts")),
         "w_up": ParamSpec((n_experts, d_model, d_ff),
-                          ("experts", "embed", "moe_mlp")),
+                          ("experts", "embed", "moe_mlp"), fan_in=d_model),
         "w_down": ParamSpec((n_experts, d_ff, d_model),
-                            ("experts", "moe_mlp", "embed")),
+                            ("experts", "moe_mlp", "embed"), fan_in=d_ff),
     }
     if kind in ("swiglu", "geglu"):
         spec["w_gate"] = ParamSpec((n_experts, d_model, d_ff),
-                                   ("experts", "embed", "moe_mlp"))
+                                   ("experts", "embed", "moe_mlp"),
+                                   fan_in=d_model)
     return spec
 
 

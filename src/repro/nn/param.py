@@ -33,6 +33,8 @@ class ParamSpec:
     init: str = "normal"                 # normal|zeros|ones|embed|trunc_fan_in
     scale: float = 1.0                   # multiplier on the init law
     dtype: Any = jnp.float32
+    #: input features of the fan-in laws; None: the first dim (rank >= 2)
+    fan_in: Optional[int] = None
 
     def __post_init__(self):
         if len(self.shape) != len(self.logical):
@@ -51,22 +53,26 @@ def abstract(spec_tree: PyTree) -> PyTree:
         is_leaf=is_spec)
 
 
+def _fan_in(spec: ParamSpec) -> int:
+    if spec.fan_in is not None:
+        return spec.fan_in
+    return spec.shape[0] if len(spec.shape) >= 2 else max(spec.shape[-1], 1)
+
+
 def _init_one(key, spec: ParamSpec):
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
     if spec.init == "normal":
-        # LeCun-style fan-in scaling on the first dim (input features).
-        fan_in = spec.shape[0] if len(spec.shape) >= 2 else max(spec.shape[-1], 1)
-        std = spec.scale / math.sqrt(fan_in)
+        # LeCun-style fan-in scaling on the input features
+        std = spec.scale / math.sqrt(_fan_in(spec))
         return (jax.random.normal(key, spec.shape, spec.dtype) * std)
     if spec.init == "embed":
         std = spec.scale
         return jax.random.normal(key, spec.shape, spec.dtype) * std
     if spec.init == "trunc_fan_in":
-        fan_in = spec.shape[0]
-        std = spec.scale / math.sqrt(fan_in)
+        std = spec.scale / math.sqrt(_fan_in(spec))
         return (jax.random.truncated_normal(key, -2.0, 2.0, spec.shape,
                                             spec.dtype) * std)
     raise ValueError(f"unknown init {spec.init!r}")
@@ -92,10 +98,12 @@ def count_params(spec_tree: PyTree) -> int:
 
 def stack_specs(spec_tree: PyTree, n: int, axis_name: Optional[str] = "layers",
                 ) -> PyTree:
-    """Prepend a stacking dim of size ``n`` to every spec (scan-over-layers)."""
+    """Prepend a stacking dim of size ``n`` to every spec (scan-over-layers);
+    each layer keeps its own fan-in."""
 
     def _stack(s: ParamSpec) -> ParamSpec:
         return ParamSpec((n,) + s.shape, (axis_name,) + s.logical,
-                         init=s.init, scale=s.scale, dtype=s.dtype)
+                         init=s.init, scale=s.scale, dtype=s.dtype,
+                         fan_in=_fan_in(s))
 
     return jax.tree.map(_stack, spec_tree, is_leaf=is_spec)

@@ -124,6 +124,10 @@ class Tracer:
             json.dump(self.chrome_trace(), f)
 
 
+#: nesting slack for recomputed span ends (float microseconds)
+_ULP_US = 1e-3
+
+
 def validate_chrome_trace(
         trace: Union[dict, Iterable[dict]]) -> List[dict]:
     """Validate trace-event schema; returns the event list.
@@ -196,7 +200,9 @@ def validate_chrome_trace(
             end = ev["ts"] + ev["dur"]
             while stack and ev["ts"] >= stack[-1]["ts"] + stack[-1]["dur"]:
                 stack.pop()
-            if stack and end > stack[-1]["ts"] + stack[-1]["dur"]:
+            # two spans closed at the same clock sample store ts and dur
+            # separately, so their recomputed ends can differ by an ulp
+            if stack and end > stack[-1]["ts"] + stack[-1]["dur"] + _ULP_US:
                 raise ValueError(
                     f"track (pid={pid}, tid={tid}): span "
                     f"{ev['name']!r} [{ev['ts']}, {end}] partially "

@@ -20,8 +20,8 @@ the dense bf16 view of the cache is never materialized.
   vectorized ops are tested against
 """
 from repro.quant.formats import (BF16, F8_E3M4, F8_E4M3, FORMATS, I8,
-                                 KVFormat, canonical_name, pool_spec,
-                                 resolve)
+                                 TPU_UNSUPPORTED, KVFormat, canonical_name,
+                                 pool_spec, require_supported, resolve)
 from repro.quant.ops import (amax_scale, dequantize, max_write_pages,
                              quantize, quantized_paged_write,
                              quantized_pool_write)
@@ -33,6 +33,7 @@ __all__ = [
     "FORMATS",
     "I8",
     "KVFormat",
+    "TPU_UNSUPPORTED",
     "amax_scale",
     "canonical_name",
     "dequantize",
@@ -41,5 +42,6 @@ __all__ = [
     "quantize",
     "quantized_paged_write",
     "quantized_pool_write",
+    "require_supported",
     "resolve",
 ]

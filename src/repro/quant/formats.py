@@ -119,6 +119,24 @@ def resolve(fmt: Union[str, KVFormat, None]) -> KVFormat:
     return FORMATS[key]
 
 
+#: formats the paged-attention kernel does not compile for on TPU: Mosaic
+#: has no vector load for float8_e3m4 ("Invalid vector type for load",
+#: compiling for v5e).  ``ServeEngine`` refuses them there up front.
+TPU_UNSUPPORTED = frozenset({"f8_e3m4"})
+
+
+def require_supported(fmt: Union[str, KVFormat, None], device) -> KVFormat:
+    """``fmt`` resolved, or ValueError when ``device`` cannot serve it."""
+    fmt = resolve(fmt)
+    if device.platform == "tpu" and fmt.name in TPU_UNSUPPORTED:
+        ok = sorted(set(FORMATS) - TPU_UNSUPPORTED)
+        raise ValueError(
+            f"KV format {fmt.name!r} is not supported on {device.device_kind}"
+            f": the paged-attention kernel does not compile for it there; "
+            f"use one of {ok}")
+    return fmt
+
+
 def canonical_name(fmt: Union[str, KVFormat, None]) -> str:
     """Canonical format name (what ``Policy.kv_dtype`` stores)."""
     return resolve(fmt).name
@@ -128,8 +146,9 @@ def pool_spec(n_pages: int, page_size: int, n_kv_heads: int, head_dim: int,
               fmt: Union[str, KVFormat], dtype=jnp.bfloat16) -> dict:
     """Abstract paged K/V pool container for one attention layer.
 
-    bf16 passthrough: ``{"k", "v"}`` pools of ``dtype`` — the PR-3
-    layout, unchanged.  Quantized formats add the fp32 scale sidecar:
+    Pools are head-major, ``(n_pages, n_kv_heads, page_size, head_dim)``
+    (see :mod:`repro.nn.attention`).  bf16 passthrough: ``{"k", "v"}``
+    pools of ``dtype``.  Quantized formats add the fp32 scale sidecar:
     ``{"k", "v", "k_scale", "v_scale"}`` with the pools in the format's
     storage dtype and ``(n_pages, n_kv_heads)`` scales (one amax scale
     per page per kv head — K rows of one page share a head's scale, so
@@ -137,7 +156,7 @@ def pool_spec(n_pages: int, page_size: int, n_kv_heads: int, head_dim: int,
     than the pool it describes).
     """
     fmt = resolve(fmt)
-    shape = (n_pages, page_size, n_kv_heads, head_dim)
+    shape = (n_pages, n_kv_heads, page_size, head_dim)
     if not fmt.quantized:
         return {"k": jax.ShapeDtypeStruct(shape, dtype),
                 "v": jax.ShapeDtypeStruct(shape, dtype)}

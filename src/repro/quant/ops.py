@@ -92,8 +92,8 @@ def quantized_paged_write(pages: jnp.ndarray, scales: jnp.ndarray,
                           positions: jnp.ndarray, valid: jnp.ndarray, *,
                           page_size: int, fmt: Union[str, KVFormat],
                           ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Quantizing scatter of ``vals`` (B, C, K, D) into ``pages``
-    (P, ps, K, D) with the ``(P, K)`` fp32 ``scales`` sidecar.
+    """Quantizing scatter of ``vals`` (B, C, K, D) into head-major
+    ``pages`` (P, K, ps, D) with the ``(P, K)`` fp32 ``scales`` sidecar.
 
     ``positions`` (B, C) are absolute token positions (``positions[:, 0]``
     is the slot's chunk start, the serving layout), ``valid`` (B,) the
@@ -142,8 +142,9 @@ def quantized_paged_write(pages: jnp.ndarray, scales: jnp.ndarray,
     phys = jnp.where(live, phys, n_pages)          # dead/sentinel -> OOB
     safe = jnp.clip(phys, 0, n_pages - 1)
 
-    # gather the touched pages, dequantize with their current scales
-    cur = pages[safe]                              # (B, wp, ps, K, D)
+    # gather the touched pages, dequantize with their current scales; the
+    # splice below works token-major, (B, wp, ps, K, D)
+    cur = pages[safe].swapaxes(2, 3)               # (B, wp, ps, K, D)
     cur_s = scales[safe]                           # (B, wp, K)
     x = dequantize(cur, cur_s[:, :, None, :, None])
     kd = x.shape[3:]
@@ -168,7 +169,8 @@ def quantized_paged_write(pages: jnp.ndarray, scales: jnp.ndarray,
     q = quantize(x, new_s[:, :, None, :, None], fmt)
 
     flat = phys.reshape(-1)
-    pages = pages.at[flat].set(q.reshape((-1, ps) + kd), mode="drop")
+    pages = pages.at[flat].set(
+        q.swapaxes(2, 3).reshape((-1,) + pages.shape[1:]), mode="drop")
     scales = scales.at[flat].set(
         new_s.astype(jnp.float32).reshape(-1, kd[0]), mode="drop")
     return pages, scales

@@ -65,7 +65,7 @@ def quantized_paged_write_ref(pages: np.ndarray, scales: np.ndarray,
     against the vectorized op's pages via ``.astype(float32)``.
     """
     fmt = resolve(fmt)
-    n_pages, ps, n_kv, d = pages.shape
+    n_pages, n_kv, ps, d = pages.shape
     pages = np.asarray(jnp.asarray(pages).astype(jnp.float32)).copy()
     scales = np.asarray(scales, np.float32).copy()
     b, c = positions.shape
@@ -85,18 +85,18 @@ def quantized_paged_write_ref(pages: np.ndarray, scales: np.ndarray,
             if phys >= n_pages:
                 continue
             if phys not in touched:
-                x = pages[phys] * scales[phys][None, :, None]
+                x = pages[phys] * scales[phys][:, None, None]
                 for r in range(ps):
                     if logical * ps + r >= end:
-                        x[r] = 0.0
+                        x[:, r] = 0.0
                 touched[phys] = x
-            touched[phys][pos % ps] = np.asarray(vals[s, t], np.float32)
+            touched[phys][:, pos % ps] = np.asarray(vals[s, t], np.float32)
 
     for phys, x in touched.items():
         for h in range(n_kv):
-            amax = float(np.max(np.abs(x[:, h])))
+            amax = float(np.max(np.abs(x[h])))
             scale = max(amax / fmt.fmax, SCALE_FLOOR)
-            q = quantize_ref(x[:, h], scale, fmt)
-            pages[phys][:, h] = np.asarray(q, np.float32)
+            q = quantize_ref(x[h], scale, fmt)
+            pages[phys][h] = np.asarray(q, np.float32)
             scales[phys, h] = scale
     return pages, scales

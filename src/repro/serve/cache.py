@@ -10,7 +10,9 @@ Every layer kind gets the state layout its decode math wants:
   memory per *admitted request* instead: a shared pool of ``num_pages``
   fixed-size pages per attention layer, and a page table row per slot
   mapping logical page -> physical page.  Token position ``p`` of slot
-  ``b`` lives at ``pages[table[b, p // page_size], p % page_size]``.
+  ``b``, kv head ``h`` lives at
+  ``pages[table[b, p // page_size], h, p % page_size]`` (head-major
+  pools, the layout the paged-attention kernel compiles for on TPU).
 - **recurrent** ('rglru', 'ssd') — O(1) per-slot decode state (the
   RG-LRU hidden vector + conv buffer, the SSD state accumulator + conv
   buffers), batch row = slot.  No pages, no page-table entries, no
@@ -29,10 +31,10 @@ once: attention layers reserve pages, recurrent layers reset their rows,
 one ``admit()`` call.
 
 **Storage precision is a policy, not a constant** (``kv_dtype``, a
-``repro.quant`` format).  The bf16 passthrough is the PR-1..4 layout:
-one ``(num_pages, page_size, K, D)`` bf16 K and V pool per attention
-layer.  Quantized formats ("i8", "f8_e4m3", "f8_e3m4") store the pools
-at 1 byte/element on the format's value grid and add a
+``repro.quant`` format).  The bf16 passthrough is one
+``(num_pages, K, page_size, D)`` bf16 K and V pool per attention layer.
+Quantized formats ("i8", "f8_e4m3", "f8_e3m4") store the pools at
+1 byte/element on the format's value grid and add a
 ``(num_pages, K)`` fp32 amax-scale *sidecar* per pool — one symmetric
 scale per (page, kv-head), ~``page_size * head_dim / 4`` times smaller
 than the pool it describes.  The write-quantize / read-dequantize

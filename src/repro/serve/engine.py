@@ -254,7 +254,9 @@ class ServeEngine:
                 tracer.thread_name(_slot_tid(b), f"slot {b}")
         if hasattr(kv_dtype, "kv_dtype"):     # a core.policy.Policy
             kv_dtype = kv_dtype.kv_dtype
-        self.kv_format = quant.resolve(kv_dtype)
+        # a format the device cannot serve fails here, naming both —
+        # never a silent fallback to another storage dtype
+        self.kv_format = quant.require_supported(kv_dtype, jax.devices()[0])
         self.spec_tokens = int(spec_tokens)
         if proposer is not None and self.spec_tokens == 0:
             raise ValueError(

@@ -91,19 +91,12 @@ def gpipe(stage_fn: Callable, mesh, *, axis: str = "pipe",
             axis)
         return outs.reshape((b,) + x.shape[1:])
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.5 top-level API
-        return jax.shard_map(
-            per_device, mesh=mesh,
-            in_specs=(P(axis), P()),
-            out_specs=P(),
-            check_vma=False,
-            axis_names={axis})
-    from jax.experimental.shard_map import shard_map
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False,
+        axis_names={axis})
 
 
 def bubble_fraction(n_stages: int, n_microbatches: int) -> float:

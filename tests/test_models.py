@@ -141,3 +141,19 @@ def test_param_counts_match_published():
     for arch, n in expected.items():
         got = T.count_params(registry.get_config(arch))
         assert abs(got - n) / n < 0.08, (arch, got, n)
+
+
+@pytest.mark.parametrize("n_layers", [1, 4])
+def test_init_scale_follows_input_features_not_layer_count(n_layers):
+    """Fan-in init divides by each weight's input features: the stacking
+    dim of scanned layers and the (heads, head_dim) input of the output
+    projection are not the fan-in."""
+    cfg = dataclasses.replace(registry.get_config("starcoder2-3b"),
+                              d_model=256, n_heads=4, n_kv_heads=2,
+                              head_dim=64, d_ff=512, vocab_size=512,
+                              n_layers=n_layers)
+    attn = T.init_params(jax.random.key(0), cfg)["scan"]["b0"]["attn"]
+    for name, fan_in in (("wq", cfg.d_model), ("wk", cfg.d_model),
+                         ("wo", cfg.n_heads * cfg.head_dim)):
+        std = float(jnp.std(attn[name]))
+        assert abs(std * np.sqrt(fan_in) - 1) < 0.05, (name, std)

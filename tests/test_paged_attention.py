@@ -43,21 +43,23 @@ def _random_paged_case(key, b, c, h, kv, d, n_pages, page_size, pmax,
     # dense logical content, scattered through the table page by page
     k_dense = jax.random.normal(ks[1], (b, pmax * page_size, kv, d), dtype)
     v_dense = jax.random.normal(ks[2], (b, pmax * page_size, kv, d), dtype)
-    pools_k = jnp.full((n_pages, page_size, kv, d), jnp.nan, dtype)
-    pools_v = jnp.full((n_pages, page_size, kv, d), jnp.nan, dtype)
+    pools_k = jnp.full((n_pages, kv, page_size, d), jnp.nan, dtype)
+    pools_v = jnp.full((n_pages, kv, page_size, d), jnp.nan, dtype)
     for s in range(b):
         length = int(start[s]) + int(valid[s])
         for pg in range(-(-length // page_size)):
             lo = pg * page_size
             n = min(page_size, length - lo)
             phys = int(table[s, pg])
-            pools_k = pools_k.at[phys, :n].set(k_dense[s, lo:lo + n])
-            pools_v = pools_v.at[phys, :n].set(v_dense[s, lo:lo + n])
+            pools_k = pools_k.at[phys, :, :n].set(
+                k_dense[s, lo:lo + n].swapaxes(0, 1))
+            pools_v = pools_v.at[phys, :, :n].set(
+                v_dense[s, lo:lo + n].swapaxes(0, 1))
             # allocated-page tails must be benign, not NaN: probs there
             # are exactly 0 but 0 * NaN would still poison the row sum
             if n < page_size:
-                pools_k = pools_k.at[phys, n:].set(0)
-                pools_v = pools_v.at[phys, n:].set(0)
+                pools_k = pools_k.at[phys, :, n:].set(0)
+                pools_v = pools_v.at[phys, :, n:].set(0)
     return q, pools_k, pools_v, jnp.asarray(table)
 
 
@@ -176,8 +178,10 @@ def test_paged_kernel_matches_contiguous_decode():
     got = paged_attention(q, pk, pv, table, jnp.asarray(start),
                           jnp.asarray(valid), interpret=True)
     tbl = jnp.clip(table, 0, 2 * pmax - 1)
-    k = jnp.nan_to_num(pk[tbl].reshape(b, pmax * page_size, kv, d))
-    v = jnp.nan_to_num(pv[tbl].reshape(b, pmax * page_size, kv, d))
+    k = jnp.nan_to_num(
+        pk[tbl].swapaxes(2, 3).reshape(b, pmax * page_size, kv, d))
+    v = jnp.nan_to_num(
+        pv[tbl].swapaxes(2, 3).reshape(b, pmax * page_size, kv, d))
     want = kref.decode_attention_ref(q[:, 0], k, v, jnp.asarray(lengths))
     np.testing.assert_allclose(np.asarray(got[:, 0], np.float32),
                                np.asarray(want, np.float32),
@@ -227,9 +231,9 @@ def _quantize_case(q, pk, pv, table, fmt):
     NaN values AND get NaN scales, so any kernel read of an unallocated
     page (values or sidecar) poisons the output and fails the isfinite
     assert."""
-    n_pages, ps, kv, d = pk.shape
+    n_pages, kv, ps, d = pk.shape
     alloc = np.unique(np.asarray(table)[np.asarray(table) < n_pages])
-    qk = jnp.zeros((n_pages, ps, kv, d), fmt.storage_dtype())
+    qk = jnp.zeros((n_pages, kv, ps, d), fmt.storage_dtype())
     qv = jnp.zeros_like(qk)
     if fmt.kind == "float":             # NaN representable: poison pools
         qk = jnp.full_like(qk, jnp.nan)
@@ -240,10 +244,10 @@ def _quantize_case(q, pk, pv, table, fmt):
         pg = int(pg)
         kb = jnp.nan_to_num(pk[pg]).astype(jnp.float32)
         vb = jnp.nan_to_num(pv[pg]).astype(jnp.float32)
-        ksc = qops.amax_scale(kb, fmt, axes=(0, 2))
-        vsc = qops.amax_scale(vb, fmt, axes=(0, 2))
-        qk = qk.at[pg].set(qops.quantize(kb, ksc[None, :, None], fmt))
-        qv = qv.at[pg].set(qops.quantize(vb, vsc[None, :, None], fmt))
+        ksc = qops.amax_scale(kb, fmt, axes=(1, 2))
+        vsc = qops.amax_scale(vb, fmt, axes=(1, 2))
+        qk = qk.at[pg].set(qops.quantize(kb, ksc[:, None, None], fmt))
+        qv = qv.at[pg].set(qops.quantize(vb, vsc[:, None, None], fmt))
         sk = sk.at[pg].set(ksc)
         sv = sv.at[pg].set(vsc)
     return qk, qv, sk, sv
@@ -253,8 +257,9 @@ def _quantize_case(q, pk, pv, table, fmt):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("c", [1, 8])
 def test_quantized_paged_kernel_vs_quantized_ref(fmt_name, dtype, c):
-    """TENTPOLE: the kernel's in-VMEM dequant (each sub-page's (1, 1)
-    blocked-VMEM scale resolved by the same index map as its values)
+    """TENTPOLE: the kernel's in-VMEM dequant (each sub-page's scale
+    selected from the resident per-head row by the same logical->physical
+    rule as its values)
     matches the quantized ragged oracle across the same mixed batch the
     bf16 tests pin — prefill chunk, mid-stream decode, fresh decode,
     idle slot — and never touches an unallocated page's values OR

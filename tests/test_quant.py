@@ -63,7 +63,7 @@ def test_storage_dtype_fp8_emulates_in_bf16_off_tpu():
 def test_pool_spec_container_layout():
     spec = quant.pool_spec(12, 16, 4, 32, "bf16")
     assert set(spec) == {"k", "v"}
-    assert spec["k"].shape == (12, 16, 4, 32)
+    assert spec["k"].shape == (12, 4, 16, 32)       # head-major (P, K, ps, D)
     assert spec["k"].dtype == jnp.bfloat16
     spec = quant.pool_spec(12, 16, 4, 32, "i8")
     assert set(spec) == {"k", "v", "k_scale", "v_scale"}
@@ -133,12 +133,12 @@ def _write_case(fmt, seed=0):
     positions = start[:, None] + np.arange(C)[None, :]
     vals = jnp.asarray(rng.normal(size=(B, C, K, D)), jnp.bfloat16)
 
-    pages = jnp.zeros((P, ps, K, D), fmt.storage_dtype())
+    pages = jnp.zeros((P, K, ps, D), fmt.storage_dtype())
     scales = jnp.full((P, K), qops.SCALE_FLOOR, jnp.float32)
     # pre-populate slot 0's first written page with quantized content
-    pre = jnp.asarray(rng.normal(size=(ps, K, D)), jnp.float32)
-    s_pre = qops.amax_scale(pre, fmt, axes=(0, 2))
-    pages = pages.at[2].set(qops.quantize(pre, s_pre[None, :, None], fmt))
+    pre = jnp.asarray(rng.normal(size=(K, ps, D)), jnp.float32)
+    s_pre = qops.amax_scale(pre, fmt, axes=(1, 2))
+    pages = pages.at[2].set(qops.quantize(pre, s_pre[:, None, None], fmt))
     scales = scales.at[2].set(s_pre)
     return (pages, scales, vals, jnp.asarray(table), jnp.asarray(positions),
             jnp.asarray(valid), ps, table, positions, valid)
@@ -194,7 +194,7 @@ def test_quantized_paged_write_incremental_decode_stability(fmt_name):
     rng = np.random.default_rng(3)
     P, ps, K, D = 4, 8, 2, 4
     table = jnp.asarray([[1, 3]], jnp.int32)
-    pages = jnp.zeros((P, ps, K, D), fmt.storage_dtype())
+    pages = jnp.zeros((P, K, ps, D), fmt.storage_dtype())
     scales = jnp.full((P, K), qops.SCALE_FLOOR, jnp.float32)
     written = []
     for pos in range(2 * ps):
@@ -205,11 +205,11 @@ def test_quantized_paged_write_incremental_decode_stability(fmt_name):
             jnp.asarray([[pos]], jnp.int32), jnp.asarray([1], jnp.int32),
             page_size=ps, fmt=fmt)
     deq = np.asarray(qops.dequantize(
-        pages, np.asarray(scales)[:, None, :, None]))
+        pages, np.asarray(scales)[:, :, None, None]))
     sc = np.asarray(scales)
     for pos, val in enumerate(written):
         phys = int(table[0, pos // ps])
-        got = deq[phys, pos % ps]
+        got = deq[phys, :, pos % ps]
         bound = _roundtrip_bound(val, sc[phys].max(), fmt)
         # a couple of requantizations may stack: allow 2x the one-shot
         # bound, still far below bf16 storage error for these magnitudes
@@ -226,10 +226,10 @@ def test_quantized_paged_write_ignores_stale_prior_tenant(fmt_name):
     fmt = quant.resolve(fmt_name)
     P, ps, K, D = 4, 8, 2, 4
     # previous tenant left huge values (amax ~50) across page 1
-    stale = jnp.full((ps, K, D), 50.0, jnp.float32)
-    s_stale = qops.amax_scale(stale, fmt, axes=(0, 2))
-    pages = jnp.zeros((P, ps, K, D), fmt.storage_dtype())
-    pages = pages.at[1].set(qops.quantize(stale, s_stale[None, :, None],
+    stale = jnp.full((K, ps, D), 50.0, jnp.float32)
+    s_stale = qops.amax_scale(stale, fmt, axes=(1, 2))
+    pages = jnp.zeros((P, K, ps, D), fmt.storage_dtype())
+    pages = pages.at[1].set(qops.quantize(stale, s_stale[:, None, None],
                                           fmt))
     scales = jnp.full((P, K), qops.SCALE_FLOOR, jnp.float32)
     scales = scales.at[1].set(s_stale)
@@ -242,20 +242,20 @@ def test_quantized_paged_write_ignores_stale_prior_tenant(fmt_name):
     # the fresh scale reflects ONLY the live row, not the stale 50s...
     assert float(np.asarray(new_s)[1].max()) <= 0.5 / fmt.fmax * 1.01
     deq = np.asarray(qops.dequantize(new_p,
-                                     np.asarray(new_s)[:, None, :, None]))
+                                     np.asarray(new_s)[:, :, None, None]))
     # ...so the live row round-trips accurately and the unreachable
     # rows are now exact zeros instead of the prior tenant's values
-    assert np.abs(deq[1, 0] - 0.5).max() <= float(
+    assert np.abs(deq[1, :, 0] - 0.5).max() <= float(
         _roundtrip_bound(np.float32(0.5), float(np.asarray(new_s)[1].max()),
                          fmt)) + 1e-6
-    assert (deq[1, 1:] == 0).all()
+    assert (deq[1, :, 1:] == 0).all()
 
 
 def test_quantized_paged_write_drops_sentinel_and_idle():
     fmt = quant.I8
     P, ps, K, D = 3, 4, 1, 2
     table = jnp.asarray([[P, P]], jnp.int32)        # nothing allocated
-    pages = jnp.zeros((P, ps, K, D), jnp.int8)
+    pages = jnp.zeros((P, K, ps, D), jnp.int8)
     scales = jnp.zeros((P, K), jnp.float32)
     new_p, new_s = qops.quantized_paged_write(
         pages, scales, jnp.ones((1, 2, K, D), jnp.bfloat16), table,

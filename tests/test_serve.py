@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import mpx, serve
+from repro import mpx, quant, serve
 from repro.configs.base import ModelConfig
 from repro.kernels import ref as kref
 from repro.kernels.decode_attention import decode_attention
@@ -744,10 +744,16 @@ def _drive_mixed_schedule(params, kv_format, use_kernel):
     return logs
 
 
-#: pinned max |logit delta| vs the bf16 cache on the mixed schedule
-#: (measured ~0.08 for i8 / ~0.23 for fp8 against logits of scale ~0.6;
-#: pinned at ~2x so real regressions trip it, bf16 noise never does)
-KV_LOGIT_TOL = {"i8": 0.15, "f8_e4m3": 0.35, "f8_e3m4": 0.35}
+#: pinned max |logit delta| vs the bf16 cache on the mixed schedule.
+#: Measured on CPU (gather / kernel path) against baseline logits of
+#: absmax 0.59: i8 0.011 / 0.016, f8_e4m3 0.048 / 0.046, f8_e3m4
+#: 0.021 / 0.019; pinned at ~2x the larger, so real regressions trip it
+#: and bf16 noise never does.  The earlier pins (0.15 / 0.35 / 0.35) were
+#: fitted when stacked layer weights drew their init scale from the layer
+#: count instead of their input features: attention scores were O(10),
+#: softmax close to one-hot, and the deltas depended on the parameter
+#: draw (they failed for i8 and f8_e4m3 once JAX changed its RNG).
+KV_LOGIT_TOL = {"i8": 0.03, "f8_e4m3": 0.10, "f8_e3m4": 0.045}
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
@@ -802,6 +808,24 @@ def test_engine_kv_i8_end_to_end(params):
     assert leaf["k"].dtype == jnp.int8
     assert leaf["k_scale"].dtype == jnp.float32
     assert leaf["k_scale"].shape[-1] == CFG.n_kv_heads
+
+
+def test_engine_refuses_kv_format_the_device_cannot_serve(params,
+                                                          monkeypatch):
+    """A KV format whose kernel does not compile for the chip fails at
+    construction, naming the format and the device — never a silent
+    fallback to another storage dtype."""
+    class FakeTPU:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [FakeTPU()])
+    for name in sorted(quant.TPU_UNSUPPORTED):
+        with pytest.raises(ValueError, match=rf"'{name}'.*TPU v5 lite"):
+            serve.ServeEngine(CFG, params, n_slots=2, max_seq=32,
+                              page_size=8, kv_dtype=name)
+    for name in sorted(set(quant.FORMATS) - quant.TPU_UNSUPPORTED):
+        assert quant.require_supported(name, FakeTPU()).name == name
 
 
 def test_engine_kv_dtype_accepts_policy(params):
