@@ -1,0 +1,2 @@
+"""Whole training step's share of the chip's bf16 peak; moves train_tokens_per_s."""
+from bench.readers import train_step_mfu as read  # noqa: F401
