@@ -10,6 +10,11 @@ Works with any optimizer following the optax ``init/update`` protocol
 (``repro.optim`` provides AdamW/SGD/Adafactor implementations).  The select
 is a pair of ``jnp.where``-on-pytrees, which XLA fuses into the update — a
 skipped step costs the same FLOPs but commits no state change.
+
+Device scope (:func:`repro.obs.trace.scope`): ``optimizer``, the select
+included.  XLA fuses the select into the update, and a fusion carries the
+scope of its root: under ``mpx`` the select would charge the whole fused
+update to mixed precision.
 """
 from __future__ import annotations
 
@@ -19,6 +24,7 @@ import jax
 
 from repro.core.filtering import (combine, is_inexact_array, partition,
                                   select_tree)
+from repro.obs.trace import scope
 
 PyTree = Any
 
@@ -48,10 +54,11 @@ def optimizer_update(model: PyTree, optimizer, optimizer_state: PyTree,
     ``grads_finite`` is False.
     """
     params, static = partition(model, is_inexact_array)
-    updates, new_opt_state = optimizer.update(grads, optimizer_state,
-                                              params=params)
-    new_params = apply_updates(params, updates)
-
-    new_params = select_tree(grads_finite, new_params, params)
-    new_opt_state = select_tree(grads_finite, new_opt_state, optimizer_state)
+    with scope("optimizer"):
+        updates, new_opt_state = optimizer.update(grads, optimizer_state,
+                                                  params=params)
+        new_params = apply_updates(params, updates)
+        new_params = select_tree(grads_finite, new_params, params)
+        new_opt_state = select_tree(grads_finite, new_opt_state,
+                                    optimizer_state)
     return combine(new_params, static), new_opt_state

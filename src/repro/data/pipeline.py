@@ -22,6 +22,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.obs.trace import program_span
 
 
 class SyntheticTokens:
@@ -135,7 +136,9 @@ class Prefetcher:
 
     ``state()`` reflects the number of batches *consumed*, not produced, so
     a checkpoint/restore never skips or replays batches that were sitting
-    in the queue.
+    in the queue.  ``next_batch`` opens a ``data wait`` program span
+    (:func:`repro.obs.trace.program_span`) around its blocking get, so a
+    profiler trace shows how long the step waited for input.
     """
 
     def __init__(self, source, depth: int = 2):
@@ -157,7 +160,8 @@ class Prefetcher:
                     continue
 
     def next_batch(self) -> dict:
-        batch = self._queue.get()
+        with program_span("data wait"):
+            batch = self._queue.get()
         self._consumed += 1
         return batch
 

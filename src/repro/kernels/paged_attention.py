@@ -279,6 +279,7 @@ def paged_attention(q, k_pages, v_pages, page_table, start, valid, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * kv, cg, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(table, start, valid, *inputs)
     return (out.reshape(b, kv, c, group, d).transpose(0, 2, 1, 3, 4)
             .reshape(b, c, h, d))

@@ -28,6 +28,13 @@ Precision: the *caller* (``mpx.filter_value_and_grad``) casts params and
 batch to the compute dtype; this module only pins the known-fragile spots to
 fp32 (softmax, norms, router, recurrent gates/decays, softcaps, loss lse) —
 exactly the paper's Example-1 discipline.
+
+Device scopes (:func:`repro.obs.trace.scope`): ``embed``; ``layers`` around
+the block scan and the unrolled tail (so the scan's stacking and copies,
+and the norms and residual adds of each block, are charged to it); the
+mixer and MLP calls of a block under their kind (``ssd``, ``attention``,
+``rglru``, ``mlp``, ``moe``); ``unembed_loss`` around the final norm, the
+logits and the cross-entropy.
 """
 from __future__ import annotations
 
@@ -43,6 +50,7 @@ from repro.nn import attention, embedding, moe as moe_lib, mlp as mlp_lib
 from repro.nn import param as P
 from repro.nn import rglru, ssd
 from repro.nn.norms import apply_norm, norm_spec
+from repro.obs.trace import scope
 from repro.sharding.rules import shard
 
 PyTree = Any
@@ -124,36 +132,50 @@ def count_params(cfg: ModelConfig) -> int:
 # forward (training / prefill)
 # ==========================================================================
 
+def _mixer_scope(kind: str) -> str:
+    """The device scope of a layer kind's mixer."""
+    return "attention" if kind in ("attn", "local_attn") else kind
+
+
+def _mlp_scope(cfg: ModelConfig) -> str:
+    return "moe" if cfg.moe_experts > 0 else "mlp"
+
+
 def _block_apply(cfg: ModelConfig, kind: str, p: PyTree, x: jnp.ndarray,
                  aux: jnp.ndarray, positions=None):
     h = shard(apply_norm(cfg.norm, p["pre_norm"], x),
               ("batch", "seq", "embed"))
-    if kind in ("attn", "local_attn"):
-        y = attention.attention_apply(
-            p["attn"], h, n_heads=cfg.n_heads, causal=cfg.causal,
-            window=cfg.window if kind == "local_attn" else 0,
-            cap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
-            positions=positions)
-    elif kind == "rglru":
-        y = rglru.rglru_block_apply(p["rec"], h, conv_width=cfg.conv_width)
-    else:  # ssd
-        y = ssd.ssd_block_apply(p["ssd"], h, n_heads=cfg.ssm_heads,
-                                headdim=cfg.ssm_headdim,
-                                d_state=cfg.ssm_state, chunk=cfg.ssm_chunk,
-                                conv_width=cfg.conv_width)
+    with scope(_mixer_scope(kind)):
+        if kind in ("attn", "local_attn"):
+            y = attention.attention_apply(
+                p["attn"], h, n_heads=cfg.n_heads, causal=cfg.causal,
+                window=cfg.window if kind == "local_attn" else 0,
+                cap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
+                positions=positions)
+        elif kind == "rglru":
+            y = rglru.rglru_block_apply(p["rec"], h,
+                                        conv_width=cfg.conv_width)
+        else:  # ssd
+            y = ssd.ssd_block_apply(p["ssd"], h, n_heads=cfg.ssm_heads,
+                                    headdim=cfg.ssm_headdim,
+                                    d_state=cfg.ssm_state,
+                                    chunk=cfg.ssm_chunk,
+                                    conv_width=cfg.conv_width)
     if cfg.post_norm:
         y = apply_norm(cfg.norm, p["post_mix_norm"], y)
     x = x + y
     if cfg.mlp != "none":
         h = shard(apply_norm(cfg.norm, p["mlp_norm"], x),
                   ("batch", "seq", "embed"))
-        if cfg.moe_experts > 0:
-            y, moe_aux = moe_lib.moe_apply(
-                p["moe"], h, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-                kind=cfg.mlp, capacity_factor=cfg.capacity_factor)
-            aux = aux + moe_aux
-        else:
-            y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
+        with scope(_mlp_scope(cfg)):
+            if cfg.moe_experts > 0:
+                y, moe_aux = moe_lib.moe_apply(
+                    p["moe"], h, n_experts=cfg.moe_experts,
+                    top_k=cfg.moe_top_k, kind=cfg.mlp,
+                    capacity_factor=cfg.capacity_factor)
+                aux = aux + moe_aux
+            else:
+                y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
         if cfg.post_norm:
             y = apply_norm(cfg.norm, p["post_mlp_norm"], y)
         x = x + y
@@ -191,30 +213,34 @@ def forward(params: PyTree, cfg: ModelConfig, batch: dict,
     """
     # compute dtype is whatever the (possibly mpx-cast) params arrived in
     dtype = params["embed"][next(iter(params["embed"]))].dtype
-    x = _embed_inputs(params, cfg, batch, dtype)
+    with scope("embed"):
+        x = _embed_inputs(params, cfg, batch, dtype)
     aux = jnp.zeros((), jnp.float32)
     n_groups, rem = _layout(cfg)
 
-    if n_groups > 0:
-        def group_body(carry, gparams):
-            x, aux = carry
-            for i, kind in enumerate(cfg.pattern):
-                x, aux = _block_apply(cfg, kind, gparams[f"b{i}"], x, aux)
-            return (x, aux), None
+    with scope("layers"):
+        if n_groups > 0:
+            def group_body(carry, gparams):
+                x, aux = carry
+                for i, kind in enumerate(cfg.pattern):
+                    x, aux = _block_apply(cfg, kind, gparams[f"b{i}"], x,
+                                          aux)
+                return (x, aux), None
 
-        body = _remat_wrap(cfg, group_body)
-        (x, aux), _ = jax.lax.scan(body, (x, aux), params["scan"])
-    for j, kind in enumerate(rem):
-        fn = _remat_wrap(cfg, functools.partial(_block_apply, cfg, kind))
-        x, aux = fn(params[f"tail{j}"], x, aux)
+            body = _remat_wrap(cfg, group_body)
+            (x, aux), _ = jax.lax.scan(body, (x, aux), params["scan"])
+        for j, kind in enumerate(rem):
+            fn = _remat_wrap(cfg, functools.partial(_block_apply, cfg, kind))
+            x, aux = fn(params[f"tail{j}"], x, aux)
 
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    if cfg.frontend == "patches":
-        n_patch = batch["patches"].shape[1] if "patches" in batch else 0
-        if n_patch:
-            x = x[:, n_patch:]
-    logits = embedding.logits_fn(params["embed"], params.get("unembed", {}),
-                                 cfg, x)
+    with scope("unembed_loss"):
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+        if cfg.frontend == "patches":
+            n_patch = batch["patches"].shape[1] if "patches" in batch else 0
+            if n_patch:
+                x = x[:, n_patch:]
+        logits = embedding.logits_fn(params["embed"],
+                                     params.get("unembed", {}), cfg, x)
     return logits, aux
 
 
@@ -235,8 +261,9 @@ def make_loss_fn(cfg: ModelConfig, moe_aux_weight: float = 0.01):
 
     def loss_fn(params, batch):
         logits, aux = forward(params, cfg, batch)
-        ce = _ce(logits, batch["targets"])
-        loss = ce + moe_aux_weight * aux
+        with scope("unembed_loss"):
+            ce = _ce(logits, batch["targets"])
+            loss = ce + moe_aux_weight * aux
         return loss, {"ce": ce, "moe_aux": aux}
 
     return loss_fn
@@ -421,34 +448,36 @@ def _block_serve(cfg: ModelConfig, kind: str, p: PyTree, pages: dict,
                  page_size: int, use_kernel: bool, pages_per_block: int = 1,
                  kv_format: str = "bf16"):
     h = apply_norm(cfg.norm, p["pre_norm"], x)
-    if kind in ("attn", "local_attn"):
-        y, pages = attention.paged_attend(
-            p["attn"], pages, page_table, h, positions, valid,
-            page_size=page_size, n_heads=cfg.n_heads,
-            window=cfg.window if kind == "local_attn" else 0,
-            cap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
-            use_kernel=use_kernel, pages_per_block=pages_per_block,
-            kv_format=kv_format)
-    elif kind == "rglru":
-        y, pages = rglru.rglru_serve_chunk(p["rec"], h, pages, valid,
+    with scope(_mixer_scope(kind)):
+        if kind in ("attn", "local_attn"):
+            y, pages = attention.paged_attend(
+                p["attn"], pages, page_table, h, positions, valid,
+                page_size=page_size, n_heads=cfg.n_heads,
+                window=cfg.window if kind == "local_attn" else 0,
+                cap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
+                use_kernel=use_kernel, pages_per_block=pages_per_block,
+                kv_format=kv_format)
+        elif kind == "rglru":
+            y, pages = rglru.rglru_serve_chunk(p["rec"], h, pages, valid,
+                                               conv_width=cfg.conv_width)
+        else:  # ssd
+            y, pages = ssd.ssd_serve_chunk(p["ssd"], h, pages, valid,
+                                           n_heads=cfg.ssm_heads,
+                                           headdim=cfg.ssm_headdim,
+                                           d_state=cfg.ssm_state,
                                            conv_width=cfg.conv_width)
-    else:  # ssd
-        y, pages = ssd.ssd_serve_chunk(p["ssd"], h, pages, valid,
-                                       n_heads=cfg.ssm_heads,
-                                       headdim=cfg.ssm_headdim,
-                                       d_state=cfg.ssm_state,
-                                       conv_width=cfg.conv_width)
     if cfg.post_norm:
         y = apply_norm(cfg.norm, p["post_mix_norm"], y)
     x = x + y
     if cfg.mlp != "none":
         h = apply_norm(cfg.norm, p["mlp_norm"], x)
-        if cfg.moe_experts > 0:
-            y, _ = moe_lib.moe_decode_apply(
-                p["moe"], h, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
-                kind=cfg.mlp)
-        else:
-            y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
+        with scope(_mlp_scope(cfg)):
+            if cfg.moe_experts > 0:
+                y, _ = moe_lib.moe_decode_apply(
+                    p["moe"], h, n_experts=cfg.moe_experts,
+                    top_k=cfg.moe_top_k, kind=cfg.mlp)
+            else:
+                y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
         if cfg.post_norm:
             y = apply_norm(cfg.norm, p["post_mlp_norm"], y)
         x = x + y
@@ -504,41 +533,45 @@ def serve_forward(params: PyTree, cfg: ModelConfig, pages: PyTree,
     """
     _require_paged_support(cfg)
     dtype = params["embed"][next(iter(params["embed"]))].dtype
-    x = embedding.embed_tokens(params["embed"], cfg, tokens, dtype)
+    with scope("embed"):
+        x = embedding.embed_tokens(params["embed"], cfg, tokens, dtype)
     positions = start[:, None] + jnp.arange(tokens.shape[1])[None, :]
     n_groups, rem = _layout(cfg)
     new_pages: dict = {}
 
-    if n_groups > 0:
-        def group_body(x, scanned):
-            gparams, gpages = scanned
-            new_gpages = {}
-            for i, kind in enumerate(cfg.pattern):
-                x, new_gpages[f"b{i}"] = _block_serve(
-                    cfg, kind, gparams[f"b{i}"], gpages[f"b{i}"],
-                    page_table, x, positions, valid,
-                    page_size=page_size, use_kernel=use_kernel,
-                    pages_per_block=pages_per_block, kv_format=kv_format)
-            return x, new_gpages
+    with scope("layers"):
+        if n_groups > 0:
+            def group_body(x, scanned):
+                gparams, gpages = scanned
+                new_gpages = {}
+                for i, kind in enumerate(cfg.pattern):
+                    x, new_gpages[f"b{i}"] = _block_serve(
+                        cfg, kind, gparams[f"b{i}"], gpages[f"b{i}"],
+                        page_table, x, positions, valid,
+                        page_size=page_size, use_kernel=use_kernel,
+                        pages_per_block=pages_per_block,
+                        kv_format=kv_format)
+                return x, new_gpages
 
-        x, new_pages["scan"] = jax.lax.scan(
-            group_body, x, (params["scan"], pages["scan"]))
-    for j, kind in enumerate(rem):
-        x, new_pages[f"tail{j}"] = _block_serve(
-            cfg, kind, params[f"tail{j}"], pages[f"tail{j}"],
-            page_table, x, positions, valid,
-            page_size=page_size, use_kernel=use_kernel,
-            pages_per_block=pages_per_block, kv_format=kv_format)
+            x, new_pages["scan"] = jax.lax.scan(
+                group_body, x, (params["scan"], pages["scan"]))
+        for j, kind in enumerate(rem):
+            x, new_pages[f"tail{j}"] = _block_serve(
+                cfg, kind, params[f"tail{j}"], pages[f"tail{j}"],
+                page_table, x, positions, valid,
+                page_size=page_size, use_kernel=use_kernel,
+                pages_per_block=pages_per_block, kv_format=kv_format)
 
     # gather the sampled window positions before the unembed so the (d, V)
     # projection runs over W positions per slot, not per padded chunk
     # position (C-fold less vocab-matmul work per step)
-    if logit_idx is None:
-        logit_idx = jnp.clip(valid - 1, 0)[:, None]          # (B, 1)
-    x = x[jnp.arange(x.shape[0])[:, None], logit_idx]        # (B, W, d)
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    logits = embedding.logits_fn(params["embed"], params.get("unembed", {}),
-                                 cfg, x)
+    with scope("unembed_loss"):
+        if logit_idx is None:
+            logit_idx = jnp.clip(valid - 1, 0)[:, None]      # (B, 1)
+        x = x[jnp.arange(x.shape[0])[:, None], logit_idx]    # (B, W, d)
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+        logits = embedding.logits_fn(params["embed"],
+                                     params.get("unembed", {}), cfg, x)
     return logits, new_pages
 
 

@@ -14,8 +14,18 @@ Four dependency-free layers instrumenting both halves of the stack:
   session renders in Perfetto as per-slot request timelines (submit →
   admit → prefill chunks → decode/spec windows with accept counts →
   truncate → retire) over an engine-phase track (plan / device step /
-  host sync / commit).  Plus :func:`~repro.obs.trace.profiler_trace`,
-  the optional ``jax.profiler`` trace-dir hook.
+  host sync / commit).  For the ``jax.profiler`` timeline:
+  :func:`~repro.obs.trace.scope` tags device work with a named scope
+  and the ``repro_scope`` frontend attribute (``embed``, ``layers``,
+  ``ssd`` / ``attention`` / ``rglru`` / ``mlp`` / ``moe``,
+  ``unembed_loss``, ``mpx``, ``optimizer``, ``sampling``), which a TPU
+  trace prints in each op's name; :func:`~repro.obs.trace.program_span`
+  opens host spans on the profiler's clock (``data wait``, ``host
+  sync``, ``checkpoint`` in each ``train`` step; the engine's ``admit``
+  / ``plan`` / ``device step`` / ``host sync`` / ``commit`` in each
+  ``serve`` step); and
+  :func:`~repro.obs.trace.profiler_trace` is the optional trace-dir
+  hook.
 - :mod:`~repro.obs.precision` — the MPX §3.3 signals:
   :class:`PrecisionStats` (loss-scale trajectory, overflow/skip-step
   counters, halving/doubling events) and
@@ -44,7 +54,9 @@ from repro.obs.journal import (JournalDivergence, JournalError,
                                read_journal, replay_journal)
 from repro.obs.registry import (Counter, Gauge, Histogram, Registry,
                                 merged_prometheus, merged_snapshot)
-from repro.obs.trace import Tracer, profiler_trace, validate_chrome_trace
+from repro.obs.trace import (SCOPES, SPANS, PhaseSpans, Tracer,
+                             profiler_trace, program_span, scope,
+                             validate_chrome_trace)
 
 __all__ = [
     "Counter",
@@ -55,17 +67,22 @@ __all__ = [
     "JournalMismatch",
     "JournalRecorder",
     "JournalTruncated",
+    "PhaseSpans",
     "PrecisionStats",
     "Registry",
     "ReplayReport",
+    "SCOPES",
+    "SPANS",
     "Tracer",
     "grad_layer_names",
     "merged_prometheus",
     "merged_snapshot",
     "per_layer_grad_summary",
     "profiler_trace",
+    "program_span",
     "read_journal",
     "replay_journal",
+    "scope",
     "validate_chrome_trace",
 ]
 

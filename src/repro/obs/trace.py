@@ -1,10 +1,43 @@
-"""Span/event tracer exporting Chrome trace-event JSON (Perfetto-loadable).
+"""Tracing: a Chrome-trace span recorder, device scopes and program spans.
 
-Dependency-free, host-side, bounded.  The engine and trainer record spans
-*around* their device steps — the tracer never touches a device array, so
-enabling it adds zero device syncs to the hot path (pinned by a test).
+Three instruments:
 
-Model:
+- :class:`Tracer` records host spans on its own ``perf_counter`` clock
+  and exports Chrome trace-event JSON (Perfetto-loadable).  Dependency-
+  free, host-side, bounded; it never touches a device array, so enabling
+  it adds zero device syncs to the hot path (pinned by a test).
+- :func:`scope` tags device work at trace time.  Every op traced inside
+  ``scope(name)`` carries ``jax.named_scope(name)`` (the ``tf_op`` that
+  xprof and TensorBoard group by) and the XLA frontend attribute
+  ``repro_scope=name``, which the compiler keeps on the compiled ops and
+  a TPU profiler trace prints in each op event's name.  The innermost
+  scope wins.  The tag is compile-time only; it changes no op.
+- :func:`program_span` opens a ``jax.profiler.TraceAnnotation`` host
+  span on the profiler's own clock (the trainer and the engine wrap each
+  step in a ``StepTraceAnnotation``), so a device trace can put each idle
+  gap down to a program phase.  With no profiler running it costs about
+  a microsecond.  :class:`PhaseSpans` opens back-to-back spans for a
+  step whose phases follow one another.
+
+Device scopes (:data:`SCOPES`), placed in ``models/transformer``,
+``core/grad``, ``core/optim_update`` and ``train/steps``: ``embed``;
+``layers`` (the ``lax.scan`` over blocks and what it stacks and copies,
+plus the norms and residual adds outside the mixer and MLP calls); one
+per mixer or MLP kind (``ssd``, ``attention``, ``rglru``, ``mlp``,
+``moe``); ``unembed_loss`` (final norm, logits, cross-entropy); ``mpx``
+(the cast to the compute dtype and its transposed upcast, loss scaling,
+unscale, the finite check, the scale adjust); ``optimizer`` (clip or
+global norm, the optimizer update with the skip-step select XLA fuses
+into it, the ZeRO-1 grad sharder); ``sampling`` (the serving sampler).
+A fused op carries the scope of its root instruction.
+
+Program spans (:data:`SPANS`): ``data wait`` (``Prefetcher.next_batch``
+blocked on its queue), ``host sync`` and ``checkpoint`` inside each
+``train`` step of ``Trainer.fit``; ``admit``, ``plan``, ``device step``,
+``host sync`` and ``commit`` inside each ``serve`` step of
+``ServeEngine.step``.
+
+Tracer model:
 
 - a :class:`Tracer` holds a ring buffer (``collections.deque(maxlen=...)``)
   of trace events — a runaway serve session overwrites its oldest events
@@ -37,6 +70,9 @@ track) without needing a browser; :func:`profiler_trace` is the optional
 ``jax.profiler`` hook — a context manager that brackets a run with
 ``start_trace``/``stop_trace`` when given a directory and is a no-op
 otherwise.
+
+This module imports JAX only inside the functions that need it, so
+``import repro.obs`` stays cheap for stdlib-only consumers.
 """
 from __future__ import annotations
 
@@ -104,12 +140,6 @@ class Tracer:
             yield
         finally:
             self.complete(name, t0, self.now_us() - t0, tid=tid, args=args)
-
-    def counter(self, name: str, values: Dict[str, float],
-                tid: int = 0) -> None:
-        """A "C" counter sample (renders as a stacked area track)."""
-        self.events.append({"ph": "C", "ts": self.now_us(), "pid": self.pid,
-                            "tid": tid, "name": name, "args": dict(values)})
 
     # -- export -------------------------------------------------------------
 
@@ -198,10 +228,12 @@ def validate_chrome_trace(
         stack: List[dict] = []
         for ev in spans:
             end = ev["ts"] + ev["dur"]
-            while stack and ev["ts"] >= stack[-1]["ts"] + stack[-1]["dur"]:
+            # spans store ts and dur separately, so a recomputed end can
+            # pass the clock sample it was taken at by an ulp: a span that
+            # starts (or ends) at that sample still follows (or nests)
+            while stack and (ev["ts"] + _ULP_US
+                             >= stack[-1]["ts"] + stack[-1]["dur"]):
                 stack.pop()
-            # two spans closed at the same clock sample store ts and dur
-            # separately, so their recomputed ends can differ by an ulp
             if stack and end > stack[-1]["ts"] + stack[-1]["dur"] + _ULP_US:
                 raise ValueError(
                     f"track (pid={pid}, tid={tid}): span "
@@ -229,3 +261,65 @@ def profiler_trace(trace_dir: Optional[str] = None):
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+#: device scopes (see :func:`scope`)
+SCOPES = ("embed", "layers", "ssd", "attention", "rglru", "mlp", "moe",
+          "unembed_loss", "mpx", "optimizer", "sampling")
+
+#: the XLA frontend attribute :func:`scope` sets on the ops it encloses
+SCOPE_ATTR = "repro_scope"
+
+#: host spans the program opens on the profiler's clock
+SPANS = ("data wait", "host sync", "checkpoint", "admit", "plan",
+         "device step", "commit")
+
+
+@contextmanager
+def scope(name: str):
+    """Tag the device work traced inside with ``name`` (one of
+    :data:`SCOPES`): ``jax.named_scope(name)`` and the frontend
+    attribute ``repro_scope=name``.  Nested scopes: the innermost wins.
+    Transposed and rematerialized ops keep the scope of the forward op
+    they came from."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; one of {SCOPES}")
+    import jax
+    from jax.experimental.xla_metadata import set_xla_metadata
+    with jax.named_scope(name), set_xla_metadata(**{SCOPE_ATTR: name}):
+        yield
+
+
+def program_span(name: str):
+    """A host span named ``name`` (one of :data:`SPANS`) on the
+    profiler's timeline: a ``jax.profiler.TraceAnnotation``, a no-op
+    when no profiler runs."""
+    if name not in SPANS:
+        raise ValueError(f"unknown program span {name!r}; one of {SPANS}")
+    import jax
+    return jax.profiler.TraceAnnotation(name)
+
+
+class PhaseSpans:
+    """Back-to-back program spans: :meth:`start` closes the open span
+    and opens the next; leaving the ``with`` block closes the last, on
+    any return or exception."""
+
+    def __init__(self):
+        self._open = None
+
+    def __enter__(self) -> "PhaseSpans":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+    def start(self, name: str) -> None:
+        self.end()
+        self._open = program_span(name)
+        self._open.__enter__()
+
+    def end(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None

@@ -61,11 +61,16 @@ the ``engine`` track) and each slot's request lifecycle (``submit`` /
 ``admit`` instants, ``prefill`` chunk spans, ``decode`` window spans
 carrying ``{rid, tokens, drafts, accepted}``, ``truncate`` on rejected
 tails, ``retire``) as Chrome trace events — ``tracer.export(path)`` then
-loads in Perfetto as a per-slot timeline.  All instrumentation reads
-host state and the two ``(B,)`` arrays the step already transfers
-(``accept`` / ``token``): tracing adds **zero device syncs** to
-``step()`` (pinned by tests/test_obs.py) and <3% tok/s on the bench
-workload (``serving_obs_overhead_pct``).
+loads in Perfetto as a per-slot timeline.  Whether or not a tracer is
+passed, each tick runs in a ``StepTraceAnnotation("serve", step_num=<n>)``
+holding the same five phases as ``jax.profiler`` program spans, and the
+compiled step's device ops carry the scopes of :mod:`repro.obs.trace`
+(``embed``, ``layers``, the mixer kinds, ``unembed_loss``, ``sampling``);
+the paged kernel is named ``paged_attention`` inside ``attention``.  All
+instrumentation reads host state and the two ``(B,)`` arrays the step
+already transfers (``accept`` / ``token``): tracing adds **zero device
+syncs** to ``step()`` (pinned by tests/test_obs.py) and <3% tok/s on the
+bench workload (``serving_obs_overhead_pct``).
 
 Flight recorder (``repro.obs.journal``): passing
 ``journal=JournalRecorder(path)`` event-sources the whole drive — config
@@ -112,7 +117,7 @@ from repro import quant
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tfm
 from repro.obs.registry import Registry, merged_prometheus, merged_snapshot
-from repro.obs.trace import Tracer
+from repro.obs.trace import PhaseSpans, Tracer, scope
 from repro.serve.cache import PagedKVCache
 from repro.serve.faults import FaultInjector, InjectedFault
 from repro.serve.metrics import EngineStats, RequestMetrics
@@ -299,6 +304,7 @@ class ServeEngine:
         # drain()'s no-progress guard reads these per-tick flags
         self._last_tick_admitted = False
         self._last_tick_stepped = False
+        self._ticks = 0
         # resilience state: bounded admission, deadlines/cancellation at
         # tick boundaries, fault injection, injectable clock
         if max_queue is not None and max_queue < 1:
@@ -371,11 +377,12 @@ class ServeEngine:
             # verification, so injected poison exercises the exact guard
             # path a real quantized-overflow NaN would take
             logits = jnp.where(poison[:, None, None], jnp.nan, logits)
-            accept, token = verifier(logits, draft, draft_len, key)
-            # nonfinite-logit guard: verdict rides the two (B,) arrays
-            # already transferred (token -1 = failure sentinel) — zero
-            # added syncs
-            accept, token = guard_nonfinite(logits, accept, token)
+            with scope("sampling"):
+                accept, token = verifier(logits, draft, draft_len, key)
+                # nonfinite-logit guard: verdict rides the two (B,) arrays
+                # already transferred (token -1 = failure sentinel) — zero
+                # added syncs
+                accept, token = guard_nonfinite(logits, accept, token)
             return accept, token, new_pages
 
         # one compiled step shape AND program: (B, chunk_size) for
@@ -475,6 +482,13 @@ class ServeEngine:
         step it belongs to and ``tok_per_s`` cannot flatter the engine by
         excluding it (regression-tested against ``drain()`` wall time).
         """
+        self._ticks += 1
+        with jax.profiler.StepTraceAnnotation("serve", step_num=self._ticks), \
+                PhaseSpans() as phase:
+            return self._tick(phase)
+
+    def _tick(self, phase: PhaseSpans) -> List[RequestResult]:
+        phase.start("admit")
         tr = self.tracer
         t0 = self._clock()
         tick_us = tr.now_us() if tr is not None else 0.0
@@ -526,6 +540,7 @@ class ServeEngine:
             self._journal_tick("idle", admitted, preempted, results)
             return results
         self._last_tick_stepped = True
+        phase.start("plan")
         if tr is not None:
             plan_us = tr.now_us()
         plan = self.scheduler.plan()
@@ -544,6 +559,7 @@ class ServeEngine:
             dev_us = tr.now_us()
             tr.complete("plan", plan_us, dev_us - plan_us, tid=TID_ENGINE,
                         args=plan.summary())
+        phase.start("device step")
         try:
             if self.faults is not None:
                 # raised before the device call, while the donated page
@@ -563,9 +579,11 @@ class ServeEngine:
                 sync_us = tr.now_us()
                 tr.complete("device step", dev_us, sync_us - dev_us,
                             tid=TID_ENGINE, args={"kind": plan.kind})
+            phase.start("host sync")
             accept = np.asarray(accept)               # blocks on the device
             token = np.asarray(token)
             now = self._clock()
+            phase.start("commit")
             if tr is not None:
                 commit_us = tr.now_us()
                 tr.complete("host sync", sync_us, commit_us - sync_us,

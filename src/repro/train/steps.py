@@ -12,6 +12,12 @@ plus: microbatched gradient accumulation (``run.grad_accum > 1``) with a
 single unscale/finite-check/adjust at the end (cheaper and numerically
 identical to per-microbatch handling), metrics, and step counting.
 
+Device scopes (:func:`repro.obs.trace.scope`): casts, loss scaling,
+unscale, the finite check and the scale adjust run under ``mpx``; the
+clip or global norm, the optimizer update with its skip-step select
+(fused into it) and the ZeRO grad sharder under ``optimizer``; the model
+under its own scopes (:mod:`repro.models.transformer`).
+
 ``make_serve_step`` wraps the unified transformer's single-token decode with
 greedy sampling — the function the decode/long-context dry-run cells lower.
 """
@@ -26,6 +32,7 @@ from repro import mpx
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core.policy import Policy
 from repro.models import transformer as tfm
+from repro.obs.trace import scope
 from repro.optim import clip_by_global_norm, global_norm
 
 PyTree = Any
@@ -54,10 +61,12 @@ def _accum_grads(loss_fn, scaling, policy: Policy, params, batch, k: int,
     def scaled_loss(d, b):
         p = mpx.combine(d, static)
         if policy.is_mixed:
-            p = policy.cast_to_compute(p)
-            b = policy.cast_to_compute(b)
+            with scope("mpx"):
+                p = policy.cast_to_compute(p)
+                b = policy.cast_to_compute(b)
         loss, metrics = loss_fn(p, b)
-        return scaling.scale(loss), (loss, metrics)
+        with scope("mpx"):
+            return scaling.scale(loss), (loss, metrics)
 
     def body(acc, b):
         (_, (loss, metrics)), g = jax.value_and_grad(
@@ -72,21 +81,24 @@ def _accum_grads(loss_fn, scaling, policy: Policy, params, batch, k: int,
             lambda a, x: a + x.astype(jnp.float32) if mpx.is_inexact_array(a)
             else a, acc, g)
         if grad_sharder is not None:
-            acc = grad_sharder(acc)    # ZeRO-2: reduce-scatter into shards
+            with scope("optimizer"):
+                acc = grad_sharder(acc)  # ZeRO-2: reduce-scatter into shards
         return acc, (loss.astype(jnp.float32), metrics)
 
     acc0 = jax.tree.map(
         lambda x: jnp.zeros(x.shape, jnp.float32)
         if mpx.is_inexact_array(x) else x, diff)
     if grad_sharder is not None:
-        acc0 = grad_sharder(acc0)
+        with scope("optimizer"):
+            acc0 = grad_sharder(acc0)
     acc, (losses, metrics) = jax.lax.scan(body, acc0, mb,
                                           unroll=k if unroll else 1)
-    grads = scaling.unscale(acc)
-    grads = jax.tree.map(
-        lambda g: g / k if mpx.is_inexact_array(g) else g, grads)
-    finite = mpx.all_finite(grads)
-    new_scaling = scaling.adjust(finite)
+    with scope("mpx"):
+        grads = scaling.unscale(acc)
+        grads = jax.tree.map(
+            lambda g: g / k if mpx.is_inexact_array(g) else g, grads)
+        finite = mpx.all_finite(grads)
+        new_scaling = scaling.adjust(finite)
     loss = losses.mean()
     metrics = jax.tree.map(lambda m: jnp.mean(m), metrics)
     return new_scaling, finite, (loss, metrics), grads
@@ -134,10 +146,11 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, optimizer,
         else:
             layer_stats = {}
 
-        if run.grad_clip > 0:
-            grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
-        else:
-            gnorm = global_norm(grads)
+        with scope("optimizer"):
+            if run.grad_clip > 0:
+                grads, gnorm = clip_by_global_norm(grads, run.grad_clip)
+            else:
+                gnorm = global_norm(grads)
 
         if run.master_weights == "opt":
             # Megatron-style distributed optimizer: fp32 master weights live
@@ -146,12 +159,16 @@ def make_train_step(cfg: ModelConfig, run: RunConfig, optimizer,
             opt_state = state["opt_state"]
             master = opt_state["master"]
             inner = {k: v for k, v in opt_state.items() if k != "master"}
-            updates, inner_new = optimizer.update(grads, inner, params=master)
-            master_new = mpx.apply_updates(master, updates)
-            params_new = policy.cast_to_compute(master_new)
-            params = mpx.select_tree(finite, params_new, state["params"])
-            opt_new = {"master": master_new, **inner_new}
-            opt_state = mpx.select_tree(finite, opt_new, opt_state)
+            with scope("optimizer"):
+                updates, inner_new = optimizer.update(grads, inner,
+                                                      params=master)
+                master_new = mpx.apply_updates(master, updates)
+            with scope("mpx"):
+                params_new = policy.cast_to_compute(master_new)
+            with scope("optimizer"):
+                params = mpx.select_tree(finite, params_new, state["params"])
+                opt_new = {"master": master_new, **inner_new}
+                opt_state = mpx.select_tree(finite, opt_new, opt_state)
         else:
             params, opt_state = mpx.optimizer_update(
                 state["params"], optimizer, state["opt_state"], grads,

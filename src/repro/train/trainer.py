@@ -24,8 +24,15 @@ The production loop every launcher entry point drives:
   ``tcfg.grad_stats=True`` the jitted step additionally returns per-layer
   grad amax / nonfinite / underflow-fraction arrays (fixed shapes, no
   host callbacks) that land in the same snapshot.  Set ``log_every=1``
-  to capture every scale transition.  ``tcfg.jax_trace_dir`` brackets
-  the run with a ``jax.profiler`` device trace.
+  to capture every scale transition,
+- puts its steps on the ``jax.profiler`` timeline: each step runs in a
+  ``StepTraceAnnotation("train", step_num=<step>)`` holding the program
+  spans ``data wait`` (the prefetch queue, or the source itself without
+  prefetch), ``host sync`` (the ``log_every`` metrics fetch) and
+  ``checkpoint``; the step's device ops carry the scopes of
+  :mod:`repro.obs.trace` (``embed``, ``layers``, the mixer kinds,
+  ``unembed_loss``, ``mpx``, ``optimizer``).  ``tcfg.jax_trace_dir``
+  brackets the run with a ``jax.profiler`` device trace.
 """
 from __future__ import annotations
 
@@ -41,7 +48,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ModelConfig, RunConfig
 from repro.data.pipeline import Prefetcher
 from repro.obs.precision import PrecisionStats, grad_layer_names
-from repro.obs.trace import profiler_trace
+from repro.obs.trace import profiler_trace, program_span
 from repro.sharding import rules as R
 from repro.train import state as S
 from repro.train.steps import make_train_step
@@ -138,11 +145,40 @@ class Trainer:
         extra = {}
         if hasattr(self.data, "state"):
             extra["data"] = self.data.state()
-        step = int(jax.device_get(self.state["step"]))
-        if sync:
-            self.ckpt.save(step, self.state, extra)
-        else:
-            self.ckpt.save_async(step, self.state, extra)
+        with program_span("checkpoint"):
+            step = int(jax.device_get(self.state["step"]))
+            if sync:
+                self.ckpt.save(step, self.state, extra)
+            else:
+                self.ckpt.save_async(step, self.state, extra)
+
+    def _next_batch(self) -> dict:
+        if isinstance(self.data, Prefetcher):
+            return self.data.next_batch()    # opens its own "data wait"
+        with program_span("data wait"):
+            return self.data.next_batch()
+
+    def _log_step(self, step: int, metrics: dict, t0: float) -> None:
+        """Fetch a logged step's metrics (the host sync) and record them."""
+        m, layers = {}, {}
+        for k, v in metrics.items():
+            arr = np.asarray(v)
+            if arr.ndim == 0:
+                m[k] = float(arr)
+            elif k in _PER_LAYER_KEYS:
+                layers[k] = arr
+        m["step"] = step + 1
+        m["step_time_s"] = time.time() - t0
+        self.metrics_history.append(m)
+        self.precision.record_step(
+            step + 1, m.get("loss_scale", 1.0),
+            m.get("grads_finite", 1.0) >= 0.5)
+        if layers:
+            self.precision.record_layer_summary(self._layer_names, layers)
+        print(f"[trainer] step {step+1} "
+              f"loss={m['loss']:.4f} "
+              f"scale={m.get('loss_scale', 1):.0f} "
+              f"({m['step_time_s']*1e3:.0f}ms)")
 
     # ------------------------------------------------------------------ fit
     def fit(self) -> list[dict]:
@@ -152,45 +188,10 @@ class Trainer:
             ctx = R.axis_rules(self.mesh, self.rules)
             with ctx, profiler_trace(self.tcfg.jax_trace_dir):
                 for step in range(start, self.tcfg.total_steps):
-                    t0 = time.time()
-                    batch = self.data.next_batch()
-                    self.state, metrics = self._step(self.state, batch)
-                    if (self.tcfg.log_every and
-                            (step + 1) % self.tcfg.log_every == 0):
-                        m, layers = {}, {}
-                        for k, v in metrics.items():
-                            arr = np.asarray(v)
-                            if arr.ndim == 0:
-                                m[k] = float(arr)
-                            elif k in _PER_LAYER_KEYS:
-                                layers[k] = arr
-                        m["step"] = step + 1
-                        m["step_time_s"] = time.time() - t0
-                        self.metrics_history.append(m)
-                        self.precision.record_step(
-                            step + 1, m.get("loss_scale", 1.0),
-                            m.get("grads_finite", 1.0) >= 0.5)
-                        if layers:
-                            self.precision.record_layer_summary(
-                                self._layer_names, layers)
-                        print(f"[trainer] step {step+1} "
-                              f"loss={m['loss']:.4f} "
-                              f"scale={m.get('loss_scale', 1):.0f} "
-                              f"({m['step_time_s']*1e3:.0f}ms)")
-                    dt = time.time() - t0
-                    if self.tcfg.watchdog_s and dt > self.tcfg.watchdog_s:
-                        self._checkpoint(sync=True)
-                        raise WatchdogTimeout(
-                            f"step {step+1} took {dt:.1f}s > "
-                            f"{self.tcfg.watchdog_s}s — checkpointed; "
-                            "relaunch to resume")
-                    if (self.ckpt is not None and
-                            (step + 1) % self.tcfg.ckpt_every == 0):
-                        self._checkpoint()
-                    if self._preempted:
-                        print("[trainer] preemption signal — checkpointing")
-                        self._checkpoint(sync=True)
-                        return self.metrics_history
+                    with jax.profiler.StepTraceAnnotation(
+                            "train", step_num=step + 1):
+                        if self._train_one(step):
+                            return self.metrics_history
             self._checkpoint(sync=True)
             return self.metrics_history
         finally:
@@ -199,3 +200,27 @@ class Trainer:
             if hasattr(self.data, "close"):
                 self.data.close()
             self._restore_signals()
+
+    def _train_one(self, step: int) -> bool:
+        """Run step ``step`` (0-based); True when preempted and
+        checkpointed, so that ``fit`` returns."""
+        t0 = time.time()
+        batch = self._next_batch()
+        self.state, metrics = self._step(self.state, batch)
+        if self.tcfg.log_every and (step + 1) % self.tcfg.log_every == 0:
+            with program_span("host sync"):
+                self._log_step(step, metrics, t0)
+        dt = time.time() - t0
+        if self.tcfg.watchdog_s and dt > self.tcfg.watchdog_s:
+            self._checkpoint(sync=True)
+            raise WatchdogTimeout(
+                f"step {step+1} took {dt:.1f}s > "
+                f"{self.tcfg.watchdog_s}s — checkpointed; "
+                "relaunch to resume")
+        if self.ckpt is not None and (step + 1) % self.tcfg.ckpt_every == 0:
+            self._checkpoint()
+        if self._preempted:
+            print("[trainer] preemption signal — checkpointing")
+            self._checkpoint(sync=True)
+            return True
+        return False

@@ -316,6 +316,20 @@ def test_validate_ring_evicted_parent_still_nests():
     validate_chrome_trace(tr.chrome_trace())
 
 
+def test_validate_back_to_back_spans_whose_end_rounds_up():
+    """A span recorded as (ts, end - ts) can recompute to an end an ulp
+    past the sample it was closed at (here a long first device step, the
+    compile, early in the trace); the span starting at that sample
+    follows it and must not read as a partial overlap."""
+    dev, sync, end = 109039.952957, 1755523.795284, 1783000.0
+    assert dev + (sync - dev) > sync
+    tr = Tracer(clock=_fake_clock())
+    tr.complete("tick", dev - 10.0, end - dev + 20.0)
+    tr.complete("device step", dev, sync - dev)
+    tr.complete("host sync", sync, end - sync)
+    validate_chrome_trace(tr.chrome_trace())
+
+
 # --------------------------------------------------------------------------
 # _percentile nearest-rank edges + summary schema pin
 # --------------------------------------------------------------------------

@@ -82,3 +82,29 @@ def test_refused_formats_do_not_compile_for_v5e(one_chip, fmt_name):
     once its kernel compiles."""
     with pytest.raises(Exception, match="Mosaic"):
         _compile(one_chip, fmt_name, 1)
+
+
+def test_paged_kernel_keeps_its_name_and_scope(one_chip):
+    """A TPU trace names an op event by its HLO text: the kernel shows as
+    ``%paged_attention.<n> = ... custom-call(...)``, tagged with the
+    ``attention`` scope it runs in."""
+    from repro.obs import scope
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    args = [sds((SLOTS, 1, H, D), jnp.bfloat16),
+            sds((N_PAGES, KV, PAGE, D), jnp.bfloat16),
+            sds((N_PAGES, KV, PAGE, D), jnp.bfloat16),
+            sds((SLOTS, PMAX), jnp.int32), sds((SLOTS,), jnp.int32),
+            sds((SLOTS,), jnp.int32)]
+
+    def step(*a):
+        with scope("attention"):
+            return paged_attention(*a)
+
+    text = jax.jit(step).lower(*args).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+               and " = " in ln]
+    assert kernels
+    for ln in kernels:
+        assert ln.lstrip().startswith("%paged_attention")
+        assert 'repro_scope="attention"' in ln
