@@ -1,15 +1,21 @@
 """Mamba-2 SSD (state-space duality) block.
 
-Implements the chunked SSD algorithm from Dao & Gu (arXiv:2405.21060):
-within a chunk of Q tokens the computation is an attention-like quadratic
-form (maps onto the MXU); across chunks a compact (H, P, N) state is carried
-through a `lax.scan` — O(S·Q) work, O(S) memory, constant-size decode state.
+Implements the chunked SSD algorithm from Dao & Gu (arXiv:2405.21060 §7)
+in its chunk-parallel form.  The sequence is cut into chunks of Q tokens.
+For all chunks at once, the intra-chunk outputs are an attention-like
+quadratic form (CB^T times the causal decay, against x) and each chunk's
+own contribution to the state is one matmul; both map onto the MXU.  Only
+the compact (B, H, P, N) state recurrence across chunks is sequential: a
+``lax.scan`` of ``state * decay + local`` with no matmul in its body, so
+cost stays linear in the number of chunks.  The incoming state's
+contribution to each chunk's outputs is again computed for all chunks at
+once.  O(S·Q) work, O(S) memory, constant-size decode state.
 
 Precision (DESIGN.md §4): `softplus(dt)`, the `dt*A` cumulative sums, all
-`exp` decays and the carried state are fp32 — these are long products of
-near-one factors, exactly the compounding-rounding shape MPX's
-`force_full_precision` exists for.  The large einsums (CB^T, score·x,
-state outer products) run in the compute dtype.
+`exp` decays and the carried and per-chunk states are fp32 — these are
+long products of near-one factors, exactly the compounding-rounding shape
+MPX's `force_full_precision` exists for.  The large einsums (CB^T,
+score·x, state outer products) take compute-dtype operands.
 
 Projections are kept separate per component (z, x, B, C, dt) instead of one
 fused in_proj: identical FLOPs, but each output gets its own logical
@@ -73,11 +79,15 @@ def _gated_rmsnorm(w, y, z):
 
 
 def ssd_chunked(x, dt, a, bmat, cmat, d_skip, chunk: int):
-    """Chunked SSD scan.
+    """Chunk-parallel SSD scan.
 
     x (B,S,H,P) compute dtype; dt (B,S,H) fp32 (post-softplus);
     a (H,) fp32 negative; bmat/cmat (B,S,N) compute dtype; d_skip (H,) fp32.
     Returns y (B,S,H,P) in x.dtype.
+
+    Chunk-major layout throughout: x enters, and y leaves, as one
+    (nc,B,H*P,Q) transpose each, so every per-head matmul has (P, Q) as
+    its two minor dims and the chunk axis leads, as the scan wants it.
     """
     b, s, h, p = x.shape
     n = bmat.shape[-1]
@@ -93,45 +103,52 @@ def ssd_chunked(x, dt, a, bmat, cmat, d_skip, chunk: int):
     s_orig, s = s, s + pad
     nc = s // q
     dtype = x.dtype
+    f32 = jnp.float32
 
-    # chunked views, scan axis first
-    xc = x.reshape(b, nc, q, h, p).transpose(1, 0, 2, 3, 4)
-    dtc = dt.reshape(b, nc, q, h).transpose(1, 0, 2, 3)
-    bc = bmat.reshape(b, nc, q, n).transpose(1, 0, 2, 3)
+    # chunked views, chunk axis first; x head-major with Q minor
+    xc = x.reshape(b, nc, q, h * p).transpose(1, 0, 3, 2) \
+        .reshape(nc, b, h, p, q)
+    bc = bmat.reshape(b, nc, q, n).transpose(1, 0, 2, 3)      # (nc,B,Q,N)
     cc = cmat.reshape(b, nc, q, n).transpose(1, 0, 2, 3)
+    dtc = dt.reshape(b, nc, q, h).transpose(1, 0, 3, 2)       # (nc,B,H,Q)
+    # within-chunk cumsum as a triangular matmul: the MXU at full fp32
+    # precision, where a cumsum lowers to a slow reduce-window
+    cs = jnp.einsum("cbhs,sl->cbhl", dtc * a[None, None, :, None],
+                    jnp.triu(jnp.ones((q, q), f32)),
+                    precision=jax.lax.Precision.HIGHEST)      # fp32, <= 0
 
+    # --- intra-chunk outputs, all chunks at once (attention-like, causal) ---
+    cb = jnp.einsum("cbln,cbsn->cbls", cc, bc, preferred_element_type=f32)
+    # mask INSIDE the exponent: the upper triangle would be exp(+large)
+    # = inf, and the later 0-masking would turn its cotangent into
+    # 0 * inf = NaN.  exp(-1e30) = 0 kills value and gradient cleanly.
     tri = jnp.tril(jnp.ones((q, q), jnp.bool_))
+    ldiff = jnp.where(tri, cs[..., :, None] - cs[..., None, :], -1e30)
+    scores = (cb[:, :, None] * jnp.exp(ldiff)).astype(dtype)  # (nc,B,H,l,s)
+    xdt = xc * dtc[:, :, :, None, :].astype(dtype)
+    y_diag = jnp.einsum("cbhps,cbhls->cbhpl", xdt, scores)
 
+    # --- each chunk's own state contribution, all chunks at once ---
+    total = cs[..., -1]                                       # (nc,B,H)
+    w = (jnp.exp(total[..., None] - cs) * dtc).astype(dtype)  # (nc,B,H,Q)
+    local = jnp.einsum("cbhpq,cbqn->cbhpn", xc * w[:, :, :, None, :], bc,
+                       preferred_element_type=f32)            # (nc,B,H,P,N)
+
+    # --- inter-chunk recurrence: the only sequential part ---
     def chunk_step(state, inp):
-        """state (B,H,P,N) fp32."""
-        x_c, dt_c, b_c, c_c = inp                    # (B,Q,H,P),(B,Q,H),(B,Q,N)
-        da = dt_c * a                                 # (B,Q,H) fp32, negative
-        da_cs = jnp.cumsum(da, axis=1)                # (B,Q,H)
-        # --- intra-chunk (attention-like, causal) ---
-        cb = jnp.einsum("bln,bsn->bls", c_c, b_c).astype(jnp.float32)
-        # mask INSIDE the exponent: the upper triangle would be exp(+large)
-        # = inf, and the later 0-masking would turn its cotangent into
-        # 0 * inf = NaN.  exp(-1e30) = 0 kills value and gradient cleanly.
-        ldiff = da_cs[:, :, None, :] - da_cs[:, None, :, :]   # (B,l,s,H)
-        ldiff = jnp.where(tri[None, :, :, None], ldiff, -1e30)
-        scores = cb[..., None] * jnp.exp(ldiff)
-        y_diag = jnp.einsum("blsh,bsh,bshp->blhp",
-                            scores.astype(dtype), dt_c.astype(dtype), x_c)
-        # --- contribution of incoming state ---
-        state_decay = jnp.exp(da_cs)                  # (B,Q,H)
-        y_off = jnp.einsum("bln,bhpn->blhp", c_c,
-                           state.astype(dtype)) * state_decay[..., None].astype(dtype)
-        # --- state update ---
-        total = da_cs[:, -1, :]                       # (B,H)
-        decay_out = jnp.exp(total[:, None, :] - da_cs)  # (B,Q,H)
-        dx = (dt_c * decay_out)[..., None].astype(dtype) * x_c  # (B,Q,H,P)
-        state_new = state * jnp.exp(total)[:, :, None, None] \
-            + jnp.einsum("bqhp,bqn->bhpn", dx, b_c).astype(jnp.float32)
-        return state_new, (y_diag + y_off).astype(dtype)
+        """state (B,H,P,N) fp32; emits the state entering the chunk."""
+        decay, loc = inp
+        return state * decay[..., None, None] + loc, state
 
-    state0 = jnp.zeros((b, h, p, n), jnp.float32)
-    _, ys = jax.lax.scan(chunk_step, state0, (xc, dtc, bc, cc))
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, p)
+    state0 = jnp.zeros((b, h, p, n), f32)
+    _, states = jax.lax.scan(chunk_step, state0, (jnp.exp(total), local))
+
+    # --- contribution of each chunk's incoming state, all chunks at once ---
+    y_off = jnp.einsum("cbhpn,cbln->cbhpl", states.astype(dtype), cc) \
+        * jnp.exp(cs)[:, :, :, None, :].astype(dtype)
+
+    y = (y_diag + y_off).astype(dtype).reshape(nc, b, h * p, q)
+    y = y.transpose(1, 0, 3, 2).reshape(b, s, h, p)
     y = y + d_skip.astype(dtype)[None, None, :, None] * x
     return y[:, :s_orig] if pad else y
 

@@ -5,7 +5,9 @@ Compiles (never runs) ``repro.kernels.paged_attention`` for a described
 2 kv heads, head_dim 128, page_size 16, 1024 pages) — in bf16 and in every
 quantized KV format the serving engine accepts on TPU — and checks that
 the formats the engine refuses there still fail to compile.  Interpret
-mode cannot see the TPU compiler's tiling rules; this does.
+mode cannot see the TPU compiler's tiling rules; this does.  It also
+compiles one mamba2-130m SSD block's forward and backward at training
+shape and bounds its temporary memory.
 
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library.
@@ -108,3 +110,38 @@ def test_paged_kernel_keeps_its_name_and_scope(one_chip):
     for ln in kernels:
         assert ln.lstrip().startswith("%paged_attention")
         assert 'repro_scope="attention"' in ln
+
+
+# mamba2-130m's SSD block at published widths, one chip's training rows
+SSD_B, SSD_S, SSD_D = 8, 2048, 768
+SSD_H, SSD_P, SSD_N, SSD_Q = 24, 64, 128, 256
+# temporaries of one block's forward and backward, compiled for a described
+# v5e: 615,583,232 bytes with the chunk-parallel scan (the chunk-serial scan
+# it replaced: 1,528,395,776)
+SSD_BLOCK_TEMP_BYTES = 700_000_000
+
+
+def test_ssd_block_memory_at_published_widths(one_chip):
+    """One mamba2-130m SSD block's forward and backward (8 x 2048, bf16
+    compute) compiles for a v5e and its temporaries stay bounded: the
+    chunk-parallel scan's largest tensor is a layer's (B, H, nc, Q, Q)
+    decay, which must not be kept for every chunk and head at once in
+    float32 beside everything else."""
+    from repro.nn import param, ssd
+    spec = ssd.ssd_spec(SSD_D, SSD_H * SSD_P, SSD_H, SSD_P, SSD_N)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        param.abstract(spec))
+    xin = jax.ShapeDtypeStruct((SSD_B, SSD_S, SSD_D), jnp.bfloat16,
+                               sharding=one_chip)
+
+    def loss(p, x):
+        y = ssd.ssd_block_apply(p, x, n_heads=SSD_H, headdim=SSD_P,
+                                d_state=SSD_N, chunk=SSD_Q)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, xin).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < SSD_BLOCK_TEMP_BYTES, temp
