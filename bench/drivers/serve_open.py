@@ -1,5 +1,6 @@
-"""Driver for ``serve_open`` traffic: Poisson arrivals at the mix's fixed
-rate, judged by the tails of time to first token and between tokens.
+"""Driver for ``serve_open`` traffic: arrivals at the mix's fixed mean
+rate (``traffic.arrivals``), judged by the tails of time to first token
+and between tokens.
 
 Requests are due on a schedule drawn from the seed, whatever the engine
 is doing; each is submitted at the first tick boundary at or after its
@@ -39,18 +40,23 @@ def run(run: harness.Run) -> harness.Outcome:
     w0 = t0 + mix["preroll_s"]
     w1 = w0 + run.seconds
     setup_s = w0 - run.t_start
+    # the traced segment closes the window: stopping a profiler trace
+    # holds the engine for about as long as the segment, so it falls in
+    # the grace, and the queue wait is read over the requests due before
     ticker = serving.Traced(run, book, tracer,
-                            at=w0 + max(0.0, (run.seconds - mix["trace_s"]) / 2),
+                            at=max(w0, w1 - mix["trace_s"]),
                             seconds=mix["trace_s"])
 
     def done(now: float) -> bool:
         """Past the window, once every request due in it has its first
-        token, or the grace is spent."""
+        token and the trace has stopped, or the grace is spent."""
         if now < w1:
             return False
+        if now >= w1 + mix["grace_s"]:
+            return True
         waiting = [r for r in book.reqs.values()
                    if w0 <= r.due < w1 and not r.token_times]
-        return not waiting or now >= w1 + mix["grace_s"]
+        return not waiting and not ticker.tracing
 
     log(f"arrivals start; the window opens after {mix['preroll_s']}s")
     serving.open_loop(book, reqs, due, t0, done, ticker.tick)
@@ -63,7 +69,7 @@ def run(run: harness.Run) -> harness.Outcome:
     itl = [(b - a) * 1e3 for r in book.reqs.values()
            for a, b in zip(r.token_times, r.token_times[1:]) if w0 <= b < w1]
     wait = [(r.admitted - r.due) * 1e3 if not math.isnan(r.admitted)
-            else math.inf for r in window]
+            else math.inf for r in window if r.due < ticker.at]
     failed = sum(r.status not in (None, "ok") for r in window)
     log(f"{len(window)} requests due in the window, {len(itl)} token gaps; "
         f"{sum(r.status == 'ok' for r in window)} finished")
@@ -75,7 +81,7 @@ def run(run: harness.Run) -> harness.Outcome:
                             mix["check"]["max_requests"])
     gap, control, _ = serving.logit_gap(run, params, book, picked,
                                         fp8=run.control)
-    layer = ticker.layer
+    layer = ticker.layer()
     if layer:
         layer["queue_wait_ms"] = wait
     return harness.Outcome(

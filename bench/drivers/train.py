@@ -167,7 +167,6 @@ def run(run: harness.Run) -> harness.Outcome:
 
 
 def _traced(run, trainer, step, cfg, job) -> dict:
-    from bench.costs import ssd_lm
     harness.clear_traces(run.trace_dir)
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -184,9 +183,15 @@ def _traced(run, trainer, step, cfg, job) -> dict:
     trace = trace_reduce.load(run.trace_dir)
     harness.clear_traces(run.trace_dir)
     tokens = job["trace_steps"] * job["batch"] * job["seq"]
-    flops = tokens * ssd_lm.train_flops_per_token(run.cell.config["model"],
-                                                  job["seq"])
-    return {"trace": trace, "flops": flops, "peaks": run.peaks}
+    return {"trace": trace, "flops": tokens * flops_per_token(run.cell),
+            "peaks": run.peaks}
+
+
+def flops_per_token(cell: harness.Cell) -> float:
+    """The model's training operations a token, by the configuration's
+    ``costs`` module."""
+    return cell.costs.train_flops_per_token(cell.config["model"],
+                                            cell.traffic["seq"])
 
 
 def _numbers(losses, grad_norm, grad, change, ref, moved) -> dict:

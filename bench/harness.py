@@ -4,7 +4,8 @@ Nothing here names a configuration, a traffic mix or a metric: a cell is
 ``BENCHMARK.json``'s workload entry plus the files its names point at.
 
 - ``configs/<config>.json`` (the entry's ``file``): the registry id and
-  the model sizes as run, with the source, ``reduced`` and ``assumed``.
+  the model sizes as run, with the source, ``reduced`` and ``assumed``;
+  its ``costs`` names ``costs/<costs>.py``, the model's operation count.
 - ``traffic/<mix>.json``: ``kind`` picks ``drivers/<kind>.py``; the rest
   is that driver's parameters (lengths, arrivals, engine settings).
 - ``limits/<workload>.json``: the limit of each number ``correct`` compares.
@@ -76,6 +77,7 @@ class Cell:
     per_layer: list
     driver: Any               # drivers/<kind>.py
     readers: dict             # per-layer metric name -> read(ctx)
+    costs: Any                # costs/<config['costs']>.py
 
 
 def _reports(metric: dict, workload: str) -> bool:
@@ -100,8 +102,10 @@ def resolve(spec: dict, workload: str, root: str = ROOT) -> Cell:
     readers = {m["name"]: load_module(
         os.path.join(bench, "layer_metrics", m["name"] + ".py"),
         "bench_metric_" + m["name"].replace(".", "_")).read for m in layer}
+    costs = load_module(os.path.join(bench, "costs", config["costs"] + ".py"),
+                        "bench_costs_" + config["costs"])
     return Cell(workload, int(w["chips"]), config, traffic, limits, e2e,
-                layer, driver, readers)
+                layer, driver, readers, costs)
 
 
 def program_config(config: dict):

@@ -7,13 +7,14 @@ for want of data.
 
 The context (``Outcome.layer``) holds ``trace`` (``trace_reduce.Trace``)
 and ``peaks``; a training run adds ``flops`` (the traced steps' model
-operations), a serving run ``plans`` (each traced tick's per-slot
-``(start, valid)``), ``host_ms``, ``model``, ``engine`` and, for an
-open loop, ``queue_wait_ms``.
+operations, by the configuration's ``costs`` module), a serving run
+``plans`` (each traced tick's per-slot ``(start, valid)``), ``host_ms``,
+``model``, ``costs`` (that module), ``engine`` and, for an open loop,
+``queue_wait_ms``.
 """
 from __future__ import annotations
 
-from bench.costs import dense_lm, paged_attention
+from bench.costs import paged_attention
 from bench.serving import pct
 
 
@@ -33,13 +34,14 @@ def train_step_mfu(ctx) -> float | None:
 
 
 def serve_step_mfu(ctx) -> float | None:
-    """Operations the traced ticks' valid tokens require, over the
-    traced window, over the chip's bf16 peak (%)."""
+    """Operations the traced ticks' valid tokens require (the
+    configuration's ``costs.tick_flops``), over the traced window, over
+    the chip's bf16 peak (%)."""
     plans = ctx.get("plans")
     if not plans:
         return None
     m = ctx["model"]
-    flops = sum(dense_lm.tick_flops(m, s, v) for s, v in plans)
+    flops = sum(ctx["costs"].tick_flops(m, s, v) for s, v in plans)
     return (100.0 * flops / ctx["trace"].window_s
             / ctx["peaks"]["bf16_flops_per_s"])
 

@@ -8,7 +8,8 @@ of sequences at once (at most ``TOKENS_PER_BLOCK`` padded positions), so
 that it fits beside the bfloat16 weights it reads: each layer's weights
 are upcast to float32 when it runs.  Attention is computed in blocks of
 queries.  Sequences are padded at the end to a multiple of the query
-block; causal masking keeps the padding out of every real position.
+block, and a block's rows to a power of two with empty sequences;
+causal masking keeps the padding out of every real position.
 """
 from __future__ import annotations
 
@@ -29,12 +30,19 @@ def _padded(length: int) -> int:
     return max(Q_BLOCK, -(-length // Q_BLOCK) * Q_BLOCK)
 
 
+def _rows(count: int, n: int) -> int:
+    """Rows a block of ``count`` sequences of padded length ``n`` runs
+    as: the next power of two, within ``TOKENS_PER_BLOCK``, so that few
+    shapes compile."""
+    return min(1 << (count - 1).bit_length(), max(1, TOKENS_PER_BLOCK // n))
+
+
 def _blocks(seqs) -> list:
-    """Consecutive groups of sequence indices whose count times their
-    longest padded length stays within ``TOKENS_PER_BLOCK``."""
+    """Groups of sequence indices, longest first, whose count times
+    their longest padded length stays within ``TOKENS_PER_BLOCK``."""
     out, cur, n = [], [], 0
-    for i, seq in enumerate(seqs):
-        n_i = _padded(len(seq))
+    for i in sorted(range(len(seqs)), key=lambda i: -len(seqs[i])):
+        n_i = _padded(len(seqs[i]))
         if cur and (len(cur) + 1) * max(n, n_i) > TOKENS_PER_BLOCK:
             out.append(cur)
             cur, n = [], 0
@@ -120,7 +128,7 @@ def _block_logits(weights, config: dict, layer, seqs, positions,
                   fp8: bool):
     m, pub = config["model"], config["published"]
     n = _padded(max(len(s) for s in seqs))
-    ids = np.zeros((len(seqs), n), np.int32)
+    ids = np.zeros((_rows(len(seqs), n), n), np.int32)
     for i, s in enumerate(seqs):
         ids[i, :len(s)] = s
     tok = weights["embed"]["tok"]

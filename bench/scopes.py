@@ -1,5 +1,5 @@
-"""Device self time of the train step by program scope, and the
-program's own host spans, per traced step.
+"""Device self time by program scope, per traced training step or
+engine tick, and the program's own host spans per traced step.
 
 The program tags its device work with the XLA frontend attribute
 ``repro_scope="<scope>"`` (``repro.obs.trace.scope``).  The compiler
@@ -11,9 +11,10 @@ counts as ``unscoped``.  Self times (``Trace.ops``) never count a nested
 op twice, so the scopes and ``unscoped`` add up to the device time of
 the window.
 
-A traced step is one ``train_step dispatch`` host span of the window.
-Each reader returns None where it finds nothing to read: no op of the
-window carries a tag (a program without scopes), or no step span.
+A traced training step is one ``train_step dispatch`` host span of the
+window, a traced engine tick one ``engine tick`` span.  Each reader
+returns None where it finds nothing to read: no op of the window carries
+a tag (a program without scopes), or no step or tick span.
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ import re
 
 UNSCOPED = "unscoped"
 STEP_SPAN = "train_step dispatch"
+TICK_SPAN = "engine tick"
 DATA_WAIT = "data wait"
 _TAG = re.compile(r'repro_scope="([^"]*)"')
 
@@ -40,24 +42,25 @@ def self_ns_by_scope(ops) -> dict:
     return out
 
 
-def _steps(trace) -> int:
-    return sum(1 for name, *_ in trace.host if name == STEP_SPAN)
+def _steps(trace, span: str = STEP_SPAN) -> int:
+    return sum(1 for name, *_ in trace.host if name == span)
 
 
-def scope_ms(ctx, scope: str) -> float | None:
-    """Device self time a traced step spends under ``scope`` (ms)."""
+def scope_ms(ctx, scope: str, span: str = STEP_SPAN) -> float | None:
+    """Device self time a traced ``span`` (a training step or an engine
+    tick) spends under ``scope`` (ms)."""
     trace = ctx.get("trace")
-    if trace is None or not _steps(trace):
+    if trace is None or not _steps(trace, span):
         return None
     by_scope = self_ns_by_scope(trace.ops)
     if set(by_scope) <= {UNSCOPED}:
         return None
-    return by_scope.get(scope, 0) * 1e-6 / _steps(trace)
+    return by_scope.get(scope, 0) * 1e-6 / _steps(trace, span)
 
 
-def scope_reader(scope: str):
+def scope_reader(scope: str, span: str = STEP_SPAN):
     """``read(ctx)`` for one scope's metric file."""
-    return lambda ctx: scope_ms(ctx, scope)
+    return lambda ctx: scope_ms(ctx, scope, span)
 
 
 def data_wait_ms(ctx) -> float | None:

@@ -158,7 +158,9 @@ def warm(engine, prompt_len: int, log) -> None:
 
 class Traced:
     """Drives the window's ticks; in a traced run, a profiler trace
-    covers the whole ticks that start in ``[at, at + seconds)``."""
+    covers the whole ticks that start in ``[at, at + seconds)``.  The
+    trace is read once the run's ticks are over (``layer()``), so that
+    reading it holds up no request."""
 
     def __init__(self, run: harness.Run, book: Book, tracer, at: float,
                  seconds: float):
@@ -166,10 +168,10 @@ class Traced:
         self.at, self.seconds = at, seconds
         self.tick_starts = []
         self.ann = None
-        self.layer = {}
+        self.stopped = False
 
     def tick(self) -> list:
-        if self.tracer is None or self.layer:
+        if self.tracer is None or self.stopped:
             return self.book.tick()
         now = time.perf_counter()
         if self.ann is None:
@@ -180,8 +182,13 @@ class Traced:
         with jax.profiler.TraceAnnotation("engine tick"):
             done = self.book.tick()
         if time.perf_counter() - self.tick_starts[0] >= self.seconds:
-            self.layer = self._stop()
+            self._stop()
         return done
+
+    @property
+    def tracing(self) -> bool:
+        """A trace has started and not yet stopped."""
+        return self.ann is not None and not self.stopped
 
     def _start(self) -> None:
         harness.clear_traces(self.run.trace_dir)
@@ -192,16 +199,26 @@ class Traced:
         self.ann.__enter__()
         self.book.record_plans()
 
-    def _stop(self) -> dict:
+    def _stop(self) -> None:
+        t = time.perf_counter()
         self.ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
         self.book.stop_recording()
+        self.stopped = True
+        self.run.log(f"trace of {len(self.tick_starts)} ticks stopped in "
+                     f"{time.perf_counter() - t:.3f}s")
+
+    def layer(self) -> dict:
+        """The traced context the readers take, or ``{}`` untraced."""
+        if not self.stopped:
+            return {}
         trace = trace_reduce.load(self.run.trace_dir)
         harness.clear_traces(self.run.trace_dir)
         self._add_engine_spans(trace)
         return {"trace": trace, "plans": self.book.plans,
                 "host_ms": self._host_ms(trace),
                 "model": self.run.cell.config["model"],
+                "costs": self.run.cell.costs,
                 "engine": self.run.cell.traffic["engine"],
                 "peaks": self.run.peaks}
 

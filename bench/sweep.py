@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Find the knee of an open-loop serving cell: the highest Poisson rate
+"""Find the knee of an open-loop serving cell: the highest arrival rate
 that the engine sustains without a growing backlog.
 
     python3 bench/sweep.py --workload <name> --seed <n> \\

@@ -112,3 +112,75 @@ def test_the_train_cell_reads_every_scope_metric():
     ctx = {"trace": _trace(OPS, waits=[(10 * MS, 11 * MS)])}
     values = {n: cell.readers[n](ctx) for n in names}
     assert all(v is not None for v in values.values()), values
+
+
+SERVE_OPS = [_op("fusion.1", "embed", 0, 1 * MS),
+             _op("while.1", "layers", 1 * MS, 20 * MS),
+             _op("fusion.2", "attention", 2 * MS, 6 * MS),
+             _op("paged_attention.1", "attention", 6 * MS, 9 * MS),
+             _op("fusion.3", "mlp", 10 * MS, 16 * MS),
+             _op("copy.1", None, 20 * MS, 21 * MS),
+             _op("fusion.4", "unembed_loss", 21 * MS, 23 * MS),
+             _op("fusion.5", "sampling", 23 * MS, 24 * MS),
+             _op("fusion.6", "mlp", 50 * MS, 54 * MS)]
+
+
+def _serve_trace(ops, ticks=2):
+    """Device ops of ``ticks`` engine ticks under ``engine tick`` spans."""
+    timed = trace_reduce._self_times(ops)
+    busy = sum(e - s for s, e in trace_reduce.merge(
+        [(s, e) for _, s, e in ops]))
+    host = [(trace_reduce.WINDOW, 0, 100 * MS, 0)]
+    host += [(scopes.TICK_SPAN, k * 40 * MS, k * 40 * MS + 30 * MS, 1)
+             for k in range(ticks)]
+    return trace_reduce.Trace(window=(0, 100 * MS), ops=timed, host=host,
+                              busy_by_device=[busy])
+
+
+SERVE_METRICS = {"attention": "serve_step.attention_ms.open",
+                 "mlp": "serve_step.mlp_ms.open",
+                 "layers": "serve_step.layers_ms.open",
+                 "unscoped": "serve_step.unscoped_ms.open"}
+
+
+@pytest.mark.parametrize("scope, ms", [
+    ("attention", 3.5), ("mlp", 5.0), ("layers", 3.0), ("unscoped", 0.5)])
+def test_serve_scope_ms_per_tick(scope, ms):
+    """The loop keeps what its children leave (19 - 4 - 3 - 6 = 6 ms);
+    per tick over the two ``engine tick`` spans."""
+    cell = harness.resolve(harness.load_spec(tiny.ROOT),
+                           "starcoder2-3b.chat_open")
+    read = cell.readers[SERVE_METRICS[scope]]
+    assert read({"trace": _serve_trace(SERVE_OPS)}) == pytest.approx(ms)
+
+
+def test_serve_scopes_add_up_to_the_busy_time_of_a_tick():
+    """The four serving metrics with ``embed``, ``unembed_loss`` and
+    ``sampling`` are the device busy time per tick."""
+    trace = _serve_trace(SERVE_OPS)
+    ctx = {"trace": trace}
+    total = sum(scopes.scope_ms(ctx, s, scopes.TICK_SPAN) for s in
+                ("attention", "mlp", "layers", "unscoped", "embed",
+                 "unembed_loss", "sampling"))
+    assert total == pytest.approx(trace.busy_s * 1e3 / 2)
+
+
+def test_tick_and_step_readers_count_only_their_own_spans():
+    """A serving trace has no training step, a training trace no tick:
+    each reader finds nothing in the other's trace."""
+    serve = {"trace": _serve_trace(SERVE_OPS)}
+    train = {"trace": _trace(OPS)}
+    assert scopes.scope_ms(serve, "layers") is None
+    assert scopes.scope_ms(train, "layers", scopes.TICK_SPAN) is None
+    assert scopes.scope_ms(train, "layers") == pytest.approx(6.5)
+    no_ticks = {"trace": _serve_trace(SERVE_OPS, ticks=0)}
+    assert scopes.scope_ms(no_ticks, "attention", scopes.TICK_SPAN) is None
+
+
+def test_no_serve_tags_on_a_recorded_tpu_trace():
+    trace = trace_reduce.load(TRACE)
+    trace.host.append((scopes.TICK_SPAN, *trace.window, 1))
+    cell = harness.resolve(harness.load_spec(tiny.ROOT),
+                           "starcoder2-3b.chat_open")
+    for name in SERVE_METRICS.values():
+        assert cell.readers[name]({"trace": trace}) is None

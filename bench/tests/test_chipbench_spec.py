@@ -13,6 +13,7 @@ import re
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -245,3 +246,117 @@ def test_judge_needs_every_number_under_its_limit():
     assert ok and set(shown) == {"a"}
     line = json.dumps(shown)
     assert "limit" in line
+
+
+CHAT = "starcoder2-3b.chat_open"
+CHAT_READERS = {"serve_step.mfu.open", "paged_attention_roofline.open",
+                "engine.host_ms_per_tick.open", "scheduler.queue_wait_ms_p90",
+                "device.idle_share.open", "serve_step.attention_ms.open",
+                "serve_step.mlp_ms.open", "serve_step.layers_ms.open",
+                "serve_step.unscoped_ms.open"}
+
+
+def test_the_chat_cell_resolves_from_the_spec_alone():
+    cell = harness.resolve(SPEC, CHAT)
+    bench = os.path.join(ROOT, "bench")
+    assert cell.chips == 1 and cell.config["name"] == "starcoder2-3b"
+    assert cell.driver.__file__ == os.path.join(bench, "drivers",
+                                                "serve_open.py")
+    mix = cell.traffic
+    assert mix["kind"] == "serve_open"
+    assert isinstance(mix["rate_per_s"], float) and mix["rate_per_s"] > 0
+    assert mix["engine"] == {"n_slots": 32, "page_size": 16,
+                             "chunk_size": 32, "max_seq": 4096,
+                             "kv_dtype": "bf16", "prefix_cache": False,
+                             "pages_per_block": 8}
+    assert mix["prompt_tokens"] == {"median": 1020, "sigma": 0.5,
+                                    "min": 16, "max": 3072}
+    assert mix["new_tokens"] == {"median": 129, "sigma": 0.99,
+                                 "min": 1, "max": 1024}
+    assert mix["prompt_tokens"]["max"] + mix["new_tokens"]["max"] <= \
+        mix["engine"]["max_seq"]
+    assert set(cell.limits) == {"logit_gap"}
+    assert {m["name"] for m in cell.end_to_end} == {
+        "ttft_p90_ms", "itl_p95_ms", "setup_s"}
+    assert set(cell.readers) == CHAT_READERS
+    for name in CHAT_READERS:
+        assert os.path.isfile(os.path.join(bench, "layer_metrics",
+                                           name + ".py"))
+    assert cell.costs.__file__ == os.path.join(bench, "costs", "dense_lm.py")
+
+
+@pytest.mark.parametrize("config, module", [("mamba2-130m", "ssd_lm"),
+                                            ("starcoder2-3b", "dense_lm")])
+def test_every_config_names_its_operation_count(config, module):
+    conf = harness.read_json(os.path.join(ROOT, "bench", "configs",
+                                          config + ".json"))
+    assert conf["costs"] == module
+    workload = next(w["name"] for w in SPEC["workloads"]
+                    if w["config"] == config)
+    cell = harness.resolve(SPEC, workload)
+    assert cell.costs.__file__ == os.path.join(ROOT, "bench", "costs",
+                                               module + ".py")
+
+
+def test_the_training_count_is_the_ssd_count_as_before():
+    from bench.drivers import train
+    cell = harness.resolve(SPEC, "mamba2-130m.pretrain_2k")
+    m = cell.config["model"]
+    assert train.flops_per_token(cell) == ssd_lm.train_flops_per_token(
+        m, 2048)
+
+
+FLAT_COUNT = '''"""A made-up operation count for the tests."""
+
+
+def train_flops_per_token(m, seq):
+    return 1000.0 * seq
+
+
+def tick_flops(m, start, valid):
+    return 7 * int(sum(valid))
+'''
+
+
+def test_a_config_that_names_another_count_gets_it(tmp_path):
+    """A tiny cell whose configuration names ``flat_count`` is counted by
+    that module, in the training driver and in the serving reader."""
+    from bench.drivers import train
+    root = tiny.make_root(str(tmp_path))
+    with open(os.path.join(root, "bench", "costs", "flat_count.py"),
+              "w") as f:
+        f.write(FLAT_COUNT)
+    for name in tiny.TINY_CONFIGS:
+        path = os.path.join(root, "bench", "configs", name + ".json")
+        conf = dict(harness.read_json(path), costs="flat_count")
+        with open(path, "w") as f:
+            json.dump(conf, f)
+    spec = harness.load_spec(root)
+    cell = harness.resolve(spec, "tiny-ssd.train", root=root)
+    assert train.flops_per_token(cell) == 1000.0 * 64
+    cell = harness.resolve(spec, "tiny-gqa.open", root=root)
+    window = trace_reduce.Trace(window=(0, 10 ** 9), ops=[], host=[],
+                                busy_by_device=[1])
+    ctx = {"plans": [([0, 3], [8, 1]), ([8, 4], [2, 0])], "trace": window,
+           "model": cell.config["model"], "costs": cell.costs,
+           "peaks": {"bf16_flops_per_s": 700.0}}
+    assert readers.serve_step_mfu(ctx) == pytest.approx(
+        100.0 * 7 * (9 + 2) / 1.0 / 700.0)
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 33 + 5])
+def test_any_block_of_requests_holds_every_quantile_once(seed):
+    """Lengths and gaps cycle one seed-drawn order of the block's
+    quantiles: every ``BLOCK`` consecutive requests hold the same sizes,
+    and two seeds differ only in the order."""
+    from bench import traffic
+    dist = {"median": 1020, "sigma": 0.5, "min": 16, "max": 3072}
+    block = traffic.BLOCK
+    ref = sorted(traffic.lengths(dist, block, traffic.rng_for(1, 1)))
+    xs = traffic.lengths(dist, 5 * block, traffic.rng_for(seed, 1))
+    gaps = np.diff(traffic.arrivals(0.72, 5 * block,
+                                    traffic.rng_for(seed, 2)), prepend=0)
+    for i in range(4 * block):
+        assert sorted(xs[i:i + block]) == ref
+        assert sum(gaps[i:i + block]) == pytest.approx(sum(gaps[:block]))
+    assert len(set(xs)) == block

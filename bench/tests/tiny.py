@@ -19,17 +19,18 @@ from bench import harness  # noqa: E402
 
 TINY_CONFIGS = {
     "tiny-ssd": {
-        "registry": "mamba2-130m", "reference": "mamba2",
+        "registry": "mamba2-130m", "reference": "mamba2", "costs": "ssd_lm",
         "published": {"norm_epsilon": 1e-5},
         "model": {"n_layers": 2, "d_model": 64, "vocab_size": 512,
                   "ssm_state": 16, "ssm_headdim": 16, "ssm_expand": 2,
                   "ssm_chunk": 32, "remat": "full"}},
     "tiny-gqa": {
         "registry": "starcoder2-3b", "reference": "dense_lm",
+        "costs": "dense_lm",
         "published": {"norm_epsilon": 1e-5},
         "model": {"n_layers": 2, "d_model": 64, "n_heads": 4,
                   "n_kv_heads": 2, "head_dim": 16, "d_ff": 128,
-                  "vocab_size": 512, "rope_theta": 10000.0}},
+                  "vocab_size": 512, "rope_theta": 10000.0, "mlp": "gelu"}},
 }
 
 TINY_TRAFFIC = {

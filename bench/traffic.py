@@ -1,11 +1,15 @@
 """The one traffic generator: every mix is a data file it reads.
 
 Sizes and gaps are stratified so that every seed gives the same work in
-another order: each block of ``block`` requests holds the same quantiles
-of the mix's length distribution (and of the exponential inter-arrival
-gap), and ``--seed`` only shuffles each block and draws the token ids.
-A window that ends mid-block therefore sees nearly the same lengths and
-arrivals on every seed.
+another order: the ``block`` quantiles of the mix's length distribution
+(and of the exponential inter-arrival gap) come in an order that the
+seed draws, and that order repeats, from a point the seed draws.  Any
+``block`` consecutive requests therefore hold each quantile once, and a
+window of any length sees nearly the same lengths and arrivals on every
+seed; a fresh shuffle of every block would let a window that ends
+mid-block hold one long prompt fewer on some seeds, and move a tail such
+as a 90th-percentile time to first token by a whole quantile.  ``--seed``
+also draws the token ids.
 
 A length distribution is ``{"median": m, "sigma": s, "min": a, "max": b}``:
 lognormal with that median and log-space sigma, clipped to [a, b].
@@ -29,24 +33,29 @@ def _quantiles(block: int) -> np.ndarray:
     return (np.arange(block) + 0.5) / block
 
 
+def _cycle(base: np.ndarray, n: int, rng: np.random.Generator) -> np.ndarray:
+    """``n`` values: ``base`` in an order drawn from ``rng``, repeated,
+    starting at a drawn point of that order."""
+    order = rng.permutation(base)
+    start = int(rng.integers(len(base)))
+    return np.resize(np.roll(order, -start), n)
+
+
 def lengths(dist: dict, n: int, rng: np.random.Generator,
             block: int = BLOCK) -> np.ndarray:
-    """``n`` lengths: per block, the same clipped lognormal quantiles."""
+    """``n`` lengths: the ``block`` clipped lognormal quantiles, cycled."""
     z = np.array([NormalDist().inv_cdf(q) for q in _quantiles(block)])
     base = np.exp(math.log(dist["median"]) + dist["sigma"] * z)
     base = np.clip(np.rint(base), dist["min"], dist["max"]).astype(np.int64)
-    blocks = [rng.permutation(base) for _ in range(-(-n // block))]
-    return np.concatenate(blocks)[:n]
+    return _cycle(base, n, rng)
 
 
 def arrivals(rate: float, n: int, rng: np.random.Generator,
              block: int = BLOCK) -> np.ndarray:
-    """Due times (s) of ``n`` Poisson arrivals at ``rate`` per second:
-    per block, the same exponential gap quantiles in a shuffled order."""
-    base = -np.log1p(-_quantiles(block)) / rate
-    gaps = np.concatenate([rng.permutation(base)
-                           for _ in range(-(-n // block))])[:n]
-    return np.cumsum(gaps)
+    """Due times (s) of ``n`` arrivals at a mean ``rate`` per second: the
+    ``block`` quantiles of the exponential gap, cycled, so not a Poisson
+    stream: no stretch holds more arrivals than the one order does."""
+    return np.cumsum(_cycle(-np.log1p(-_quantiles(block)) / rate, n, rng))
 
 
 def token_ids(rng: np.random.Generator, n: int, vocab: int) -> list:
