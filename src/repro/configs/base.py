@@ -23,19 +23,27 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0                 # 0 -> d_model // n_heads
-    pattern: Tuple[str, ...] = ("attn",)  # cycled: attn|local_attn|rglru|ssd
+    # cycled: attn|local_attn|rglru|ssd|moe
+    pattern: Tuple[str, ...] = ("attn",)
     window: int = 0                   # sliding window for local_attn
     mlp: str = "swiglu"               # swiglu|geglu|gelu|none
     mlp_bias: bool = False
     moe_experts: int = 0
     moe_top_k: int = 2
     capacity_factor: float = 1.25
+    # expert-only blocks (pattern kind "moe"): the DeepSeek-V3 router
+    # (sigmoid scores, top-k, weights renormalised and scaled) over
+    # moe_experts routed experts of width d_ff, plus one shared expert
+    moe_shared_d_ff: int = 0
+    moe_held: Tuple[int, int] = (0, 0)    # (first, count) held; count 0: all
+    moe_routed_scale: float = 1.0
     qkv_bias: bool = False
     out_bias: bool = False
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     rope_theta: float = 10000.0       # 0 -> no RoPE (hubert: stub frontend owns positions)
     norm: str = "rmsnorm"             # rmsnorm|layernorm
+    norm_eps: Optional[float] = None  # None: rmsnorm 1e-6, layernorm 1e-5
     post_norm: bool = False           # gemma2: post-block norms
     causal: bool = True               # False: encoder-only (hubert)
     tie_embeddings: bool = True
@@ -45,6 +53,8 @@ class ModelConfig:
     ssm_headdim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
+    ssm_n_heads: int = 0              # 0 -> ssm_expand * d_model / ssm_headdim
+    ssm_groups: int = 1               # B/C groups; head h reads h // (H/G)
     # rglru (recurrentgemma)
     d_rnn: int = 0
     conv_width: int = 4
@@ -64,11 +74,19 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_headdim
+
+    @property
+    def moe_held_range(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts an expert-only block holds."""
+        first, count = self.moe_held
+        return first, count or self.moe_experts - first
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """The concrete kind of each of the n_layers layers."""
@@ -76,7 +94,9 @@ class ModelConfig:
         return (self.pattern * reps)[: self.n_layers]
 
     def supports_decode(self) -> bool:
-        return self.causal and self.family not in ("audio", "vision")
+        """Expert-only ("moe") blocks are trained, not served."""
+        return (self.causal and self.family not in ("audio", "vision")
+                and "moe" not in self.pattern)
 
     def sub_quadratic(self) -> bool:
         """True if every layer's decode state is bounded (or constant) —

@@ -1,14 +1,16 @@
 """Architecture registry: ``--arch <id>`` ids -> (full config, smoke config).
 
-The ten assigned architectures plus the paper's own ViT evaluation model
+The ten assigned architectures, the hybrid Mamba-2 / MoE Nemotron 3 Nano,
+plus the paper's own ViT evaluation model
 (the latter lives in :mod:`repro.models.vit` with its own config type and
 is exposed here for the benchmarks, not for the LM dry-run matrix).
 """
 from __future__ import annotations
 
 from repro.configs import (gemma2_2b, hubert_xlarge, llama3_8b, mamba2_130m,
-                           mixtral_8x7b, phi3_5_moe, phi3_vision_4_2b,
-                           qwen1_5_32b, recurrentgemma_9b, starcoder2_3b)
+                           mixtral_8x7b, nemotron_3_nano, phi3_5_moe,
+                           phi3_vision_4_2b, qwen1_5_32b, recurrentgemma_9b,
+                           starcoder2_3b)
 from repro.configs.base import ModelConfig
 
 _MODULES = {
@@ -22,6 +24,7 @@ _MODULES = {
     "hubert-xlarge": hubert_xlarge,
     "phi-3-vision-4.2b": phi3_vision_4_2b,
     "mamba2-130m": mamba2_130m,
+    "nemotron-3-nano-30b-a3b": nemotron_3_nano,
 }
 
 ARCH_IDS = tuple(_MODULES)

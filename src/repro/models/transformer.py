@@ -2,7 +2,9 @@
 
 A :class:`~repro.configs.base.ModelConfig` describes the stack as a repeating
 ``pattern`` of layer kinds (``attn`` / ``local_attn`` / ``rglru`` / ``ssd``),
-each followed by a dense or MoE MLP (or none).  Repeated pattern groups are
+each followed by a dense or MoE MLP (or none), and expert-only blocks
+(``moe``: a held share of sigmoid-routed experts plus a shared expert,
+trained, not served).  Repeated pattern groups are
 parameter-stacked and driven by ``jax.lax.scan`` — the single most important
 compile-time lever for the 512-device dry-run (HLO contains one group body,
 not ``n_layers`` copies).  Remainder layers (n_layers % len(pattern)) are
@@ -13,6 +15,7 @@ Public API (all pure functions; ``params`` is a nested dict pytree):
 - ``abstract_params(cfg)``                 -> ParamSpec tree
 - ``init_params(key, cfg)``                -> fp32 parameter tree
 - ``forward(params, cfg, batch, policy)``  -> (logits, aux_loss)
+- ``forward_with_stats(params, cfg, batch)`` -> (logits, aux_loss, counters)
 - ``loss_fn(params, cfg, batch)``          -> (loss, metrics)   [MPX-ready]
 - ``abstract_cache(cfg, batch, max_seq)``  -> decode-state tree (ShapeDtype)
 - ``decode(params, cfg, cache, tokens, pos)`` -> (logits, new_cache)
@@ -33,8 +36,12 @@ Device scopes (:func:`repro.obs.trace.scope`): ``embed``; ``layers`` around
 the block scan and the unrolled tail (so the scan's stacking and copies,
 and the norms and residual adds of each block, are charged to it); the
 mixer and MLP calls of a block under their kind (``ssd``, ``attention``,
-``rglru``, ``mlp``, ``moe``); ``unembed_loss`` around the final norm, the
-logits and the cross-entropy.
+``rglru``, ``mlp``, ``moe``), with an expert-only block's routing, sort,
+dispatch and combine under ``moe_route``; ``unembed_loss`` around the
+final norm, the logits and the cross-entropy.
+
+Remat (``cfg.remat``) wraps each block, so a block's backward holds that
+block's recomputed activations only.
 """
 from __future__ import annotations
 
@@ -49,7 +56,7 @@ from repro.configs.base import ModelConfig
 from repro.nn import attention, embedding, moe as moe_lib, mlp as mlp_lib
 from repro.nn import param as P
 from repro.nn import rglru, ssd
-from repro.nn.norms import apply_norm, norm_spec
+from repro.nn.norms import RMS_EPS, apply_norm, norm_spec
 from repro.obs.trace import scope
 from repro.sharding.rules import shard
 
@@ -72,7 +79,11 @@ def _block_spec(cfg: ModelConfig, kind: str) -> dict:
     elif kind == "ssd":
         spec["ssd"] = ssd.ssd_spec(d, cfg.d_inner, cfg.ssm_heads,
                                    cfg.ssm_headdim, cfg.ssm_state,
-                                   cfg.conv_width)
+                                   cfg.conv_width, cfg.ssm_groups)
+    elif kind == "moe":
+        spec["moe"] = moe_lib.held_experts_spec(
+            d, cfg.d_ff, cfg.moe_experts, cfg.moe_held_range[1],
+            cfg.moe_shared_d_ff)
     else:
         raise ValueError(f"unknown layer kind {kind!r}")
     if cfg.post_norm:
@@ -132,6 +143,10 @@ def count_params(cfg: ModelConfig) -> int:
 # forward (training / prefill)
 # ==========================================================================
 
+def _norm(cfg: ModelConfig, p: PyTree, x: jnp.ndarray) -> jnp.ndarray:
+    return apply_norm(cfg.norm, p, x, cfg.norm_eps)
+
+
 def _mixer_scope(kind: str) -> str:
     """The device scope of a layer kind's mixer."""
     return "attention" if kind in ("attn", "local_attn") else kind
@@ -141,31 +156,52 @@ def _mlp_scope(cfg: ModelConfig) -> str:
     return "moe" if cfg.moe_experts > 0 else "mlp"
 
 
+def _moe_stats(cfg: ModelConfig) -> dict:
+    """Counters an expert-only block adds to its step's metrics: routed
+    assignments that reached a held expert (summed over the blocks), and
+    the busiest held expert's load over the held experts' mean (the
+    largest over the blocks)."""
+    if "moe" not in cfg.pattern:
+        return {}
+    return {"moe_held_assignments": jnp.zeros((), jnp.float32),
+            "moe_held_load_max": jnp.zeros((), jnp.float32)}
+
+
 def _block_apply(cfg: ModelConfig, kind: str, p: PyTree, x: jnp.ndarray,
-                 aux: jnp.ndarray, positions=None):
-    h = shard(apply_norm(cfg.norm, p["pre_norm"], x),
+                 aux: jnp.ndarray, stats: dict):
+    h = shard(_norm(cfg, p["pre_norm"], x),
               ("batch", "seq", "embed"))
     with scope(_mixer_scope(kind)):
         if kind in ("attn", "local_attn"):
             y = attention.attention_apply(
                 p["attn"], h, n_heads=cfg.n_heads, causal=cfg.causal,
                 window=cfg.window if kind == "local_attn" else 0,
-                cap=cfg.attn_softcap, rope_theta=cfg.rope_theta,
-                positions=positions)
+                cap=cfg.attn_softcap, rope_theta=cfg.rope_theta)
         elif kind == "rglru":
             y = rglru.rglru_block_apply(p["rec"], h,
                                         conv_width=cfg.conv_width)
+        elif kind == "moe":
+            y, counts = moe_lib.held_experts_apply(
+                p["moe"], h, n_routed=cfg.moe_experts, top_k=cfg.moe_top_k,
+                held=cfg.moe_held_range, routed_scale=cfg.moe_routed_scale)
+            stats = {
+                "moe_held_assignments": stats["moe_held_assignments"]
+                + counts["moe_held_assignments"],
+                "moe_held_load_max": jnp.maximum(
+                    stats["moe_held_load_max"], counts["moe_held_load_max"])}
         else:  # ssd
             y = ssd.ssd_block_apply(p["ssd"], h, n_heads=cfg.ssm_heads,
                                     headdim=cfg.ssm_headdim,
                                     d_state=cfg.ssm_state,
                                     chunk=cfg.ssm_chunk,
-                                    conv_width=cfg.conv_width)
+                                    conv_width=cfg.conv_width,
+                                    n_groups=cfg.ssm_groups,
+                                    eps=cfg.norm_eps or RMS_EPS)
     if cfg.post_norm:
-        y = apply_norm(cfg.norm, p["post_mix_norm"], y)
+        y = _norm(cfg, p["post_mix_norm"], y)
     x = x + y
     if cfg.mlp != "none":
-        h = shard(apply_norm(cfg.norm, p["mlp_norm"], x),
+        h = shard(_norm(cfg, p["mlp_norm"], x),
                   ("batch", "seq", "embed"))
         with scope(_mlp_scope(cfg)):
             if cfg.moe_experts > 0:
@@ -177,9 +213,9 @@ def _block_apply(cfg: ModelConfig, kind: str, p: PyTree, x: jnp.ndarray,
             else:
                 y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
         if cfg.post_norm:
-            y = apply_norm(cfg.norm, p["post_mlp_norm"], y)
+            y = _norm(cfg, p["post_mlp_norm"], y)
         x = x + y
-    return shard(x, ("batch", "seq", "embed")), aux
+    return shard(x, ("batch", "seq", "embed")), aux, stats
 
 
 def _remat_wrap(cfg: ModelConfig, fn):
@@ -211,37 +247,47 @@ def forward(params: PyTree, cfg: ModelConfig, batch: dict,
     For the VLM the returned logits cover only the text positions (the
     patch prefix is stripped before the head).
     """
+    logits, aux, _ = forward_with_stats(params, cfg, batch)
+    return logits, aux
+
+
+def forward_with_stats(params: PyTree, cfg: ModelConfig, batch: dict,
+                       ) -> tuple[jnp.ndarray, jnp.ndarray, dict]:
+    """:func:`forward` plus the expert-only blocks' routing counters
+    (empty without such blocks)."""
     # compute dtype is whatever the (possibly mpx-cast) params arrived in
     dtype = params["embed"][next(iter(params["embed"]))].dtype
     with scope("embed"):
         x = _embed_inputs(params, cfg, batch, dtype)
-    aux = jnp.zeros((), jnp.float32)
+    carry = (x, jnp.zeros((), jnp.float32), _moe_stats(cfg))
     n_groups, rem = _layout(cfg)
+
+    def block(kind: str):
+        """One block as ``(carry, params) -> carry``, remat'd alone."""
+        return _remat_wrap(
+            cfg, lambda carry, p: _block_apply(cfg, kind, p, *carry))
 
     with scope("layers"):
         if n_groups > 0:
             def group_body(carry, gparams):
-                x, aux = carry
                 for i, kind in enumerate(cfg.pattern):
-                    x, aux = _block_apply(cfg, kind, gparams[f"b{i}"], x,
-                                          aux)
-                return (x, aux), None
+                    carry = block(kind)(carry, gparams[f"b{i}"])
+                return carry, None
 
-            body = _remat_wrap(cfg, group_body)
-            (x, aux), _ = jax.lax.scan(body, (x, aux), params["scan"])
+            carry, _ = jax.lax.scan(group_body, carry, params["scan"])
         for j, kind in enumerate(rem):
-            fn = _remat_wrap(cfg, functools.partial(_block_apply, cfg, kind))
-            x, aux = fn(params[f"tail{j}"], x, aux)
+            carry = block(kind)(carry, params[f"tail{j}"])
+    x, aux, stats = carry
 
     with scope("unembed_loss"):
-        x = apply_norm(cfg.norm, params["final_norm"], x)
+        x = _norm(cfg, params["final_norm"], x)
         if cfg.frontend == "patches":
             n_patch = batch["patches"].shape[1] if "patches" in batch else 0
             if n_patch:
                 x = x[:, n_patch:]
         logits = embedding.logits_fn(params["embed"],
                                      params.get("unembed", {}), cfg, x)
-    return logits, aux
+    return logits, aux, stats
 
 
 # ==========================================================================
@@ -260,11 +306,11 @@ def make_loss_fn(cfg: ModelConfig, moe_aux_weight: float = 0.01):
     """Returns ``loss(params, batch) -> (loss, metrics)`` for mpx.filter_*."""
 
     def loss_fn(params, batch):
-        logits, aux = forward(params, cfg, batch)
+        logits, aux, stats = forward_with_stats(params, cfg, batch)
         with scope("unembed_loss"):
             ce = _ce(logits, batch["targets"])
             loss = ce + moe_aux_weight * aux
-        return loss, {"ce": ce, "moe_aux": aux}
+        return loss, {"ce": ce, "moe_aux": aux, **stats}
 
     return loss_fn
 
@@ -273,8 +319,16 @@ def make_loss_fn(cfg: ModelConfig, moe_aux_weight: float = 0.01):
 # decode (single-token serve step)
 # ==========================================================================
 
+def _refuse_unserved(cfg: ModelConfig, kind: str) -> None:
+    if kind == "moe":
+        raise ValueError(
+            f"{cfg.name}: layer kind 'moe' (expert-only blocks) is trained, "
+            f"not served: it has no decode or serving path")
+
+
 def _block_state_spec(cfg: ModelConfig, kind: str, batch: int, max_seq: int,
                       dtype):
+    _refuse_unserved(cfg, kind)
     if kind in ("attn", "local_attn"):
         window = cfg.window if kind == "local_attn" else 0
         return attention.init_cache_spec(batch, max_seq, cfg.n_kv_heads,
@@ -316,7 +370,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
 
 def _block_decode(cfg: ModelConfig, kind: str, p: PyTree, st: PyTree,
                   x: jnp.ndarray, pos):
-    h = apply_norm(cfg.norm, p["pre_norm"], x)
+    _refuse_unserved(cfg, kind)
+    h = _norm(cfg, p["pre_norm"], x)
     if kind in ("attn", "local_attn"):
         y, st = attention.decode_step(
             p["attn"], st, h, pos, n_heads=cfg.n_heads,
@@ -332,10 +387,10 @@ def _block_decode(cfg: ModelConfig, kind: str, p: PyTree, st: PyTree,
                                     chunk=cfg.ssm_chunk,
                                     conv_width=cfg.conv_width, state=st)
     if cfg.post_norm:
-        y = apply_norm(cfg.norm, p["post_mix_norm"], y)
+        y = _norm(cfg, p["post_mix_norm"], y)
     x = x + y
     if cfg.mlp != "none":
-        h = apply_norm(cfg.norm, p["mlp_norm"], x)
+        h = _norm(cfg, p["mlp_norm"], x)
         if cfg.moe_experts > 0:
             y, _ = moe_lib.moe_decode_apply(
                 p["moe"], h, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
@@ -343,7 +398,7 @@ def _block_decode(cfg: ModelConfig, kind: str, p: PyTree, st: PyTree,
         else:
             y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
         if cfg.post_norm:
-            y = apply_norm(cfg.norm, p["post_mlp_norm"], y)
+            y = _norm(cfg, p["post_mlp_norm"], y)
         x = x + y
     return x, st
 
@@ -359,6 +414,7 @@ _RECURRENT_KINDS = ("rglru", "ssd")
 def _require_paged_support(cfg: ModelConfig) -> None:
     bad = [k for k in cfg.layer_kinds() if k not in _SERVABLE_KINDS]
     if bad:
+        _refuse_unserved(cfg, bad[0])
         raise ValueError(
             f"{cfg.name}: layer kind {bad[0]!r} has no serving state-pool "
             f"implementation; the paged state pool serves attention "
@@ -447,7 +503,8 @@ def _block_serve(cfg: ModelConfig, kind: str, p: PyTree, pages: dict,
                  page_table, x: jnp.ndarray, positions, valid, *,
                  page_size: int, use_kernel: bool, pages_per_block: int = 1,
                  kv_format: str = "bf16"):
-    h = apply_norm(cfg.norm, p["pre_norm"], x)
+    _refuse_unserved(cfg, kind)
+    h = _norm(cfg, p["pre_norm"], x)
     with scope(_mixer_scope(kind)):
         if kind in ("attn", "local_attn"):
             y, pages = attention.paged_attend(
@@ -467,10 +524,10 @@ def _block_serve(cfg: ModelConfig, kind: str, p: PyTree, pages: dict,
                                            d_state=cfg.ssm_state,
                                            conv_width=cfg.conv_width)
     if cfg.post_norm:
-        y = apply_norm(cfg.norm, p["post_mix_norm"], y)
+        y = _norm(cfg, p["post_mix_norm"], y)
     x = x + y
     if cfg.mlp != "none":
-        h = apply_norm(cfg.norm, p["mlp_norm"], x)
+        h = _norm(cfg, p["mlp_norm"], x)
         with scope(_mlp_scope(cfg)):
             if cfg.moe_experts > 0:
                 y, _ = moe_lib.moe_decode_apply(
@@ -479,7 +536,7 @@ def _block_serve(cfg: ModelConfig, kind: str, p: PyTree, pages: dict,
             else:
                 y = mlp_lib.mlp_apply(cfg.mlp, p["mlp"], h)
         if cfg.post_norm:
-            y = apply_norm(cfg.norm, p["post_mlp_norm"], y)
+            y = _norm(cfg, p["post_mlp_norm"], y)
         x = x + y
     return x, pages
 
@@ -569,7 +626,7 @@ def serve_forward(params: PyTree, cfg: ModelConfig, pages: PyTree,
         if logit_idx is None:
             logit_idx = jnp.clip(valid - 1, 0)[:, None]      # (B, 1)
         x = x[jnp.arange(x.shape[0])[:, None], logit_idx]    # (B, W, d)
-        x = apply_norm(cfg.norm, params["final_norm"], x)
+        x = _norm(cfg, params["final_norm"], x)
         logits = embedding.logits_fn(params["embed"],
                                      params.get("unembed", {}), cfg, x)
     return logits, new_pages
@@ -599,7 +656,7 @@ def decode(params: PyTree, cfg: ModelConfig, cache: PyTree,
         x, new_cache[f"tail{j}"] = _block_decode(
             cfg, kind, params[f"tail{j}"], cache[f"tail{j}"], x, pos)
 
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = _norm(cfg, params["final_norm"], x)
     logits = embedding.logits_fn(params["embed"], params.get("unembed", {}),
                                  cfg, x)
     return logits, new_cache

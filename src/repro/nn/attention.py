@@ -1,7 +1,11 @@
 """Multi-head attention: GQA, RoPE, sliding windows, logit softcap, caches.
 
-Three execution paths share one set of parameters:
+Four execution paths share one set of parameters:
 
+- ``attend_splash``  — on a TPU, causal self-attention with no window or
+  softcap runs through the Pallas splash-attention kernel (GQA as grouped
+  query heads over each KV head), whose backward kernels keep O(S) memory
+  per head: the training path at 8k positions and beyond.
 - ``attend_plain``   — masked einsum softmax; small sequences (<= 4k).
 - ``attend_blocked`` — query-block × key-block streaming softmax with fp32
   running (max, sum, acc) state.  This is the flash-attention recurrence
@@ -187,6 +191,48 @@ def attend_blocked(q, k, v, *, causal: bool, window: int, cap: float,
 
 #: sequences above this use the blocked path (never materialize S×S scores)
 BLOCKED_THRESHOLD = 8192
+#: splash kernel tile (query and key rows) in every phase
+SPLASH_BLOCK = 512
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _splash_fits(s: int, causal: bool, window: int, cap: float) -> bool:
+    """The splash kernel takes this call: a TPU, causal, no window or
+    softcap, whole tiles, and no mesh to partition it over."""
+    from repro.sharding import rules as _R
+    mesh, _ = _R._get_ctx()
+    return (_on_tpu() and causal and window == 0 and cap <= 0
+            and s % min(SPLASH_BLOCK, s) == 0 and s % 128 == 0
+            and (mesh is None or mesh.size == 1))
+
+
+def _splash_kernel(heads: int, seq: int):
+    """A causal splash kernel, made at each trace: the mask arrays it
+    holds are constants of the trace that makes them."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    blk = min(SPLASH_BLOCK, seq)
+    mask = sm.MultiHeadMask([sm.CausalMask((seq, seq))] * heads)
+    sizes = sk.BlockSizes(block_q=blk, block_kv=blk, block_kv_compute=blk,
+                          block_q_dkv=blk, block_kv_dkv=blk,
+                          block_kv_dkv_compute=blk, block_q_dq=blk,
+                          block_kv_dq=blk)
+    return sk.make_splash_mha_single_device(mask, block_sizes=sizes)
+
+
+def attend_splash(q, k, v) -> jnp.ndarray:
+    """Causal self-attention through the splash kernel: q (B,S,H,D),
+    k/v (B,S,K,D) with K dividing H -> (B,S,H,D).  The kernel takes
+    head-major rows and unscaled logits, so q is scaled first."""
+    b, s, h, d = q.shape
+    kernel = _splash_kernel(h, s)
+    qh = (q * jnp.asarray(1.0 / math.sqrt(d), q.dtype)).transpose(0, 2, 1, 3)
+    out = jax.vmap(kernel)(qh, k.transpose(0, 2, 1, 3),
+                           v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)
 
 
 def attention_apply(params, x, *, n_heads: int, causal: bool, window: int,
@@ -206,7 +252,9 @@ def attention_apply(params, x, *, n_heads: int, causal: bool, window: int,
     mesh, _ = _R._get_ctx()
     msize = mesh.shape.get("model", 1) if mesh is not None else 1
     heads_shard = msize > 1 and n_heads % msize == 0
-    if blocked:
+    if use_blocked is None and _splash_fits(s, causal, window, cap):
+        out = attend_splash(q, k, v)
+    elif blocked:
         out = attend_blocked(q, _expand_kv(k, n_heads),
                              _expand_kv(v, n_heads),
                              causal=causal, window=window, cap=cap)

@@ -15,6 +15,14 @@ Sharding: the expert dim maps to the "model" mesh axis when divisible
 (phi3.5: 16 experts on 16-way TP = pure expert parallelism); otherwise the
 expert-internal hidden dim is TP-sharded (mixtral: 8 experts, d_ff 14336).
 Both come from the same rule table — no code change.
+
+:func:`held_experts_apply` is the expert-only block of the DeepSeek-V3
+family (Nemotron-H): a sigmoid router over all ``n_routed`` experts, of
+which this layer holds a contiguous range, squared-ReLU experts run as a
+grouped matmul (megablox ``gmm``) over the held experts' tokens only,
+with no capacity and no drop, and an always-on shared expert.  Routing,
+sort, dispatch and combine run under the ``moe_route`` scope inside
+``moe``.
 """
 from __future__ import annotations
 
@@ -26,6 +34,7 @@ import jax.numpy as jnp
 
 from repro import mpx
 from repro.nn.param import ParamSpec
+from repro.obs.trace import scope
 from repro.sharding import rules as R
 from repro.sharding.rules import shard
 
@@ -219,3 +228,133 @@ def moe_apply(params, x: jnp.ndarray, *, n_experts: int, top_k: int,
                    )(y_e, flat_e, pos_c, keep, gate)
     out = shard(out, ("moe_group", None, "embed"))
     return out.reshape(b, s, d), jnp.mean(aux)
+
+
+# --------------------------------------------------------------------------
+# expert-only block: a held share of sigmoid-routed experts + shared expert
+# --------------------------------------------------------------------------
+
+#: rows of a grouped-matmul tile: each held expert's last tile is padding
+GMM_TILE_M = 128
+
+
+def held_experts_spec(d_model: int, d_ff: int, n_routed: int, held: int,
+                      shared_d_ff: int):
+    """Router over all ``n_routed`` experts; the ``held`` experts' up and
+    down projections with the expert axis second, so that each leaf's
+    first axis is its input features; the shared expert."""
+    spec = {
+        "router": ParamSpec((d_model, n_routed), ("embed", "experts")),
+        "w_up": ParamSpec((d_model, held, d_ff),
+                          ("embed", "experts", "moe_mlp"), fan_in=d_model),
+        "w_down": ParamSpec((d_ff, held, d_model),
+                            ("moe_mlp", "experts", "embed"), fan_in=d_ff),
+    }
+    if shared_d_ff:
+        spec["shared_up"] = ParamSpec((d_model, shared_d_ff), ("embed", "mlp"))
+        spec["shared_down"] = ParamSpec((shared_d_ff, d_model),
+                                        ("mlp", "embed"))
+    return spec
+
+
+def _interpret() -> bool:
+    """Pallas kernels run natively on a TPU, interpreted elsewhere."""
+    return jax.default_backend() != "tpu"
+
+
+def _tile(dim: int) -> int:
+    """The widest of 512, 384, 256, 128 that divides ``dim``, else ``dim``."""
+    return next((t for t in (512, 384, 256, 128) if dim % t == 0), dim)
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    return GMM_TILE_M, _tile(k), _tile(n)
+
+
+def _grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` rows in groups of ``group_sizes`` (one more group than
+    ``rhs`` has: the rows no held expert takes, whose output is zero)
+    times their group's (k, n) matrix of ``rhs``."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, _gmm_tiling,
+                        None, None, False, _interpret())
+
+
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
+def held_experts_apply(params, x: jnp.ndarray, *, n_routed: int, top_k: int,
+                       held: tuple[int, int], routed_scale: float,
+                       ) -> tuple[jnp.ndarray, dict]:
+    """x (B,S,d) -> (this layer's share of the expert output (B,S,d),
+    routing counters).
+
+    The router scores every token against all ``n_routed`` experts in
+    fp32 (a float32 matmul at full precision): sigmoid scores, the top
+    ``top_k`` of score plus a per-expert correction bias (zero here, and
+    not a parameter), weights the picked scores over their sum times
+    ``routed_scale``.  Of these assignments
+    the ones to the ``held = (first, count)`` experts are sorted by
+    expert into a buffer of ``B*S*min(top_k, count)`` rows, as many as
+    the held experts can ever receive, so that none is dropped; the
+    grouped matmul computes only the held experts' rows.  The shared
+    expert sees every token.  Counters: the assignments that reached a
+    held expert, and the busiest held expert's over the mean.
+    """
+    b, s, d = x.shape
+    t = b * s
+    first, count = held
+    dtype = x.dtype
+    f32 = jnp.float32
+    xf = x.reshape(t, d)
+    m = -(-t * min(top_k, count) // GMM_TILE_M) * GMM_TILE_M
+
+    with scope("moe_route"):
+        def _route(xin):
+            # HIGHEST: a float32 matmul on a TPU otherwise takes one
+            # bfloat16 pass, and the top-k would follow its rounding
+            return jnp.matmul(xin, params["router"].astype(f32),
+                              precision=jax.lax.Precision.HIGHEST)
+
+        logits = mpx.force_full_precision(_route, None)(xf)      # (T,E) fp32
+        scores = jax.nn.sigmoid(logits)
+        bias = jnp.zeros((n_routed,), f32)      # correction bias: zero
+        _, expert = jax.lax.top_k(scores + bias, top_k)         # (T,k)
+        gate = jnp.take_along_axis(scores, expert, axis=-1)
+        gate = gate / gate.sum(-1, keepdims=True) * routed_scale
+        local = expert.reshape(-1) - first                      # (T·k,)
+        mine = (local >= 0) & (local < count)
+        local = jnp.where(mine, local, count)   # not held: sorted last
+        order = jnp.argsort(local, stable=True)
+        if m > t * top_k:
+            order = jnp.pad(order, (0, m - t * top_k))
+        rows = order[:m]
+        counts = jnp.sum(jax.nn.one_hot(local, count, dtype=jnp.int32), 0)
+        sizes = jnp.concatenate([counts, m - counts.sum(keepdims=True)])
+        xs = xf[rows // top_k]                                  # (m,d)
+        # each assignment's row in the buffer; past the buffer: no row
+        pos = jnp.zeros((t * top_k,), jnp.int32).at[order[:t * top_k]] \
+            .set(jnp.arange(t * top_k, dtype=jnp.int32),
+                 unique_indices=True).reshape(t, top_k)
+        gate = jnp.where(mine.reshape(t, top_k), gate, 0.0)
+
+    w_up = params["w_up"].astype(dtype).transpose(1, 0, 2)      # (E,d,f)
+    w_down = params["w_down"].astype(dtype).transpose(1, 0, 2)  # (E,f,d)
+    h = _relu2(_grouped_matmul(xs, w_up, sizes))
+    ys = _grouped_matmul(h, w_down, sizes)                      # (m,d)
+
+    with scope("moe_route"):
+        out = jnp.zeros((t, d), f32)
+        for j in range(top_k):
+            yj = ys.at[pos[:, j]].get(mode="fill", fill_value=0)
+            out = out + yj.astype(f32) * gate[:, j:j + 1]
+        out = out.astype(dtype)
+    if "shared_up" in params:
+        out = out + _relu2(xf @ params["shared_up"].astype(dtype)) \
+            @ params["shared_down"].astype(dtype)
+    total = counts.sum().astype(f32)
+    stats = {"moe_held_assignments": total,
+             "moe_held_load_max": counts.max().astype(f32) * count
+             / jnp.maximum(total, 1.0)}
+    return out.reshape(b, s, d), stats

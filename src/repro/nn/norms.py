@@ -7,6 +7,8 @@ never accumulates a mean or variance in half precision.
 """
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 
 from repro import mpx
@@ -22,13 +24,18 @@ def layernorm_spec(dim: int, logical: str = "embed"):
             "bias": ParamSpec((dim,), (logical,), init="zeros")}
 
 
-def _rms_stats(x32: jnp.ndarray) -> jnp.ndarray:
-    return jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+#: each norm's eps where the configuration gives none
+RMS_EPS, LN_EPS = 1e-6, 1e-5
 
 
-def rmsnorm(params, x: jnp.ndarray) -> jnp.ndarray:
+def _rms_stats(x32: jnp.ndarray, eps: float) -> jnp.ndarray:
+    return jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+
+
+def rmsnorm(params, x: jnp.ndarray, eps: float = RMS_EPS) -> jnp.ndarray:
     """RMSNorm; statistics in fp32, output in ``x.dtype``."""
-    rms = mpx.force_full_precision(_rms_stats, None)(x)
+    rms = mpx.force_full_precision(functools.partial(_rms_stats, eps=eps),
+                                   None)(x)
     y = (x.astype(jnp.float32) / rms) * params["scale"].astype(jnp.float32)
     return y.astype(x.dtype)
 
@@ -39,10 +46,10 @@ def _ln_stats(x32: jnp.ndarray):
     return mean, var
 
 
-def layernorm(params, x: jnp.ndarray) -> jnp.ndarray:
+def layernorm(params, x: jnp.ndarray, eps: float = LN_EPS) -> jnp.ndarray:
     """LayerNorm; statistics in fp32, output in ``x.dtype``."""
     mean, var = mpx.force_full_precision(_ln_stats, None)(x)
-    inv = (var + 1e-5) ** -0.5  # fp32
+    inv = (var + eps) ** -0.5  # fp32
     y = (x.astype(jnp.float32) - mean) * inv
     y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
     return y.astype(x.dtype)
@@ -56,8 +63,11 @@ def norm_spec(kind: str, dim: int):
     raise ValueError(kind)
 
 
-def apply_norm(kind: str, params, x):
-    return rmsnorm(params, x) if kind == "rmsnorm" else layernorm(params, x)
+def apply_norm(kind: str, params, x, eps: float | None = None):
+    """``kind``'s norm with ``eps``, or with its own default if None."""
+    if kind == "rmsnorm":
+        return rmsnorm(params, x, RMS_EPS if eps is None else eps)
+    return layernorm(params, x, LN_EPS if eps is None else eps)
 
 
 def softcap(x: jnp.ndarray, cap: float) -> jnp.ndarray:

@@ -24,7 +24,9 @@ Device scopes (:data:`SCOPES`), placed in ``models/transformer``,
 ``layers`` (the ``lax.scan`` over blocks and what it stacks and copies,
 plus the norms and residual adds outside the mixer and MLP calls); one
 per mixer or MLP kind (``ssd``, ``attention``, ``rglru``, ``mlp``,
-``moe``); ``unembed_loss`` (final norm, logits, cross-entropy); ``mpx``
+``moe``); ``moe_route`` inside ``moe`` (an expert-only block's router,
+top-k, sort, dispatch and combine, apart from the expert matmuls);
+``unembed_loss`` (final norm, logits, cross-entropy); ``mpx``
 (the cast to the compute dtype and its transposed upcast, loss scaling,
 unscale, the finite check, the scale adjust); ``optimizer`` (clip or
 global norm, the optimizer update with the skip-step select XLA fuses
@@ -265,7 +267,7 @@ def profiler_trace(trace_dir: Optional[str] = None):
 
 #: device scopes (see :func:`scope`)
 SCOPES = ("embed", "layers", "ssd", "attention", "rglru", "mlp", "moe",
-          "unembed_loss", "mpx", "optimizer", "sampling")
+          "moe_route", "unembed_loss", "mpx", "optimizer", "sampling")
 
 #: the XLA frontend attribute :func:`scope` sets on the ops it encloses
 SCOPE_ATTR = "repro_scope"

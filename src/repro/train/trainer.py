@@ -31,8 +31,11 @@ The production loop every launcher entry point drives:
   prefetch), ``host sync`` (the ``log_every`` metrics fetch) and
   ``checkpoint``; the step's device ops carry the scopes of
   :mod:`repro.obs.trace` (``embed``, ``layers``, the mixer kinds,
-  ``unembed_loss``, ``mpx``, ``optimizer``).  ``tcfg.jax_trace_dir``
-  brackets the run with a ``jax.profiler`` device trace.
+  ``unembed_loss``, ``mpx``, ``optimizer``).  A model with expert-only
+  blocks logs, at each sync, the routed assignments that reached its
+  held experts and the busiest held expert's load over their mean.
+  ``tcfg.jax_trace_dir`` brackets the run with a ``jax.profiler`` device
+  trace.
 """
 from __future__ import annotations
 
@@ -175,9 +178,13 @@ class Trainer:
             m.get("grads_finite", 1.0) >= 0.5)
         if layers:
             self.precision.record_layer_summary(self._layer_names, layers)
+        experts = ""
+        if "moe_held_assignments" in m:
+            experts = (f"held={m['moe_held_assignments']:.0f} "
+                       f"load_max={m['moe_held_load_max']:.2f} ")
         print(f"[trainer] step {step+1} "
               f"loss={m['loss']:.4f} "
-              f"scale={m.get('loss_scale', 1):.0f} "
+              f"scale={m.get('loss_scale', 1):.0f} {experts}"
               f"({m['step_time_s']*1e3:.0f}ms)")
 
     # ------------------------------------------------------------------ fit

@@ -58,7 +58,10 @@ def _compiled_train_step(cfg, batch=2, seq=32) -> str:
      {"embed", "layers", "ssd", "unembed_loss", "mpx", "optimizer"}),
     (ATTN_CFG, {"embed", "layers", "attention", "mlp", "unembed_loss",
                 "mpx", "optimizer"}),
-], ids=["ssd", "attention"])
+    (registry.get_smoke_config("nemotron-3-nano-30b-a3b"),
+     {"embed", "layers", "ssd", "attention", "moe", "moe_route",
+      "unembed_loss", "mpx", "optimizer"}),
+], ids=["ssd", "attention", "hybrid"])
 def test_compiled_train_step_carries_scopes(cfg, expected):
     found = _scopes(_compiled_train_step(cfg))
     assert expected <= found, expected - found

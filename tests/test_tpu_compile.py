@@ -12,6 +12,9 @@ shape and bounds its temporary memory.
 The topology is described inside a fixture, never at import time: only
 one process at a time may load the TPU library.
 """
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -145,3 +148,82 @@ def test_ssd_block_memory_at_published_widths(one_chip):
         params, xin).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert 0 < temp < SSD_BLOCK_TEMP_BYTES, temp
+
+
+# Nemotron 3 Nano's expert block and attention at published widths, one
+# chip's share: 2 x 8192 tokens, 8 of 128 experts held, GQA 32/2
+NEM_T, NEM_D, NEM_F, NEM_E, NEM_HELD = 2 * 8192, 2688, 1856, 128, 8
+
+
+def _kernel_lines(text: str) -> list:
+    return [ln.strip() for ln in text.splitlines()
+            if "tpu_custom_call" in ln and " = " in ln]
+
+
+def test_held_expert_block_compiles_with_grouped_matmuls(one_chip,
+                                                         monkeypatch):
+    """The held-share expert block's forward and backward compile for a
+    v5e with the megablox kernels (gmm forward and input gradient, tgmm
+    weight gradient) under ``moe``, and its routing under ``moe_route``."""
+    from jax.experimental.pallas.ops.tpu.megablox import common
+    from repro.nn import moe, param
+    from repro.obs import scope
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    monkeypatch.setattr(common, "supports_bfloat16_matmul", lambda: True)
+    spec = moe.held_experts_spec(NEM_D, NEM_F, NEM_E, NEM_HELD, 2 * NEM_F)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        param.abstract(spec))
+    x = jax.ShapeDtypeStruct((2, NEM_T // 2, NEM_D), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        with scope("moe"):
+            y, _ = moe.held_experts_apply(p, x, n_routed=NEM_E, top_k=6,
+                                          held=(0, NEM_HELD),
+                                          routed_scale=2.5)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = _kernel_lines(text)
+    names = {ln[1:].split(" ", 1)[0].rsplit(".", 1)[0] for ln in kernels}
+    assert names == {"gmm", "tgmm"}, names
+    assert all('repro_scope="moe"' in ln for ln in kernels)
+    assert 'repro_scope="moe_route"' in text
+
+
+def test_splash_attention_compiles_at_8k(one_chip, monkeypatch):
+    """Training attention at 8,192 positions takes the splash kernels
+    (forward, dq, dkv) on a TPU, tagged ``attention``."""
+    from repro.nn import attention, param
+    from repro.obs import scope
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    spec = attention.attention_spec(NEM_D, 32, 2, 128)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        param.abstract(spec))
+    x = jax.ShapeDtypeStruct((2, 8192, NEM_D), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        with scope("attention"):
+            y = attention.attention_apply(p, x, n_heads=32, causal=True,
+                                          window=0, cap=0.0, rope_theta=0.0)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = _kernel_lines(text)
+    names = {ln.split(" ", 1)[0].rsplit(".", 1)[0] for ln in kernels}
+    assert names == {"%splash_mha_fwd_residuals",
+                     "%splash_mha_dq_no_residuals",
+                     "%splash_mha_dkv_no_residuals"}, names
+    # the kernels' own metadata replaces the scope tag; the cell's readers
+    # count them under attention by name
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import kernel_time
+    assert {kernel_time.scope_of(ln) for ln in kernels} == {"attention"}
