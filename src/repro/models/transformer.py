@@ -158,13 +158,15 @@ def _mlp_scope(cfg: ModelConfig) -> str:
 
 def _moe_stats(cfg: ModelConfig) -> dict:
     """Counters an expert-only block adds to its step's metrics: routed
-    assignments that reached a held expert (summed over the blocks), and
-    the busiest held expert's load over the held experts' mean (the
-    largest over the blocks)."""
+    assignments that reached a held expert and the rows of the buffers
+    that took them (each summed over the blocks), and the busiest held
+    expert's load over the held experts' mean (the largest over the
+    blocks)."""
     if "moe" not in cfg.pattern:
         return {}
     return {"moe_held_assignments": jnp.zeros((), jnp.float32),
-            "moe_held_load_max": jnp.zeros((), jnp.float32)}
+            "moe_held_load_max": jnp.zeros((), jnp.float32),
+            "moe_dispatch_rows": jnp.zeros((), jnp.float32)}
 
 
 def _block_apply(cfg: ModelConfig, kind: str, p: PyTree, x: jnp.ndarray,
@@ -188,7 +190,9 @@ def _block_apply(cfg: ModelConfig, kind: str, p: PyTree, x: jnp.ndarray,
                 "moe_held_assignments": stats["moe_held_assignments"]
                 + counts["moe_held_assignments"],
                 "moe_held_load_max": jnp.maximum(
-                    stats["moe_held_load_max"], counts["moe_held_load_max"])}
+                    stats["moe_held_load_max"], counts["moe_held_load_max"]),
+                "moe_dispatch_rows": stats["moe_dispatch_rows"]
+                + counts["moe_dispatch_rows"]}
         else:  # ssd
             y = ssd.ssd_block_apply(p["ssd"], h, n_heads=cfg.ssm_heads,
                                     headdim=cfg.ssm_headdim,

@@ -20,9 +20,13 @@ Both come from the same rule table — no code change.
 family (Nemotron-H): a sigmoid router over all ``n_routed`` experts, of
 which this layer holds a contiguous range, squared-ReLU experts run as a
 grouped matmul (megablox ``gmm``) over the held experts' tokens only,
-with no capacity and no drop, and an always-on shared expert.  Routing,
-sort, dispatch and combine run under the ``moe_route`` scope inside
-``moe``.
+with no capacity and no drop, and an always-on shared expert.  The held
+assignments go to a buffer sized on the device from a short ladder of
+static sizes, the smallest that takes them all, and the combine
+scatter-adds the buffer's rows to their tokens, so that the routing's
+data movement follows the rows routed; the rows picked are counted
+(``moe_dispatch_rows``).  Routing, sort, dispatch and combine run under
+the ``moe_route`` scope inside ``moe``.
 """
 from __future__ import annotations
 
@@ -236,6 +240,9 @@ def moe_apply(params, x: jnp.ndarray, *, n_experts: int, top_k: int,
 
 #: rows of a grouped-matmul tile: each held expert's last tile is padding
 GMM_TILE_M = 128
+#: the held experts' buffer sizes: the worst case over powers of this
+DISPATCH_RATIO = 4
+DISPATCH_STEPS = 4
 
 
 def held_experts_spec(d_model: int, d_ff: int, n_routed: int, held: int,
@@ -271,17 +278,118 @@ def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
     return GMM_TILE_M, _tile(k), _tile(n)
 
 
-def _grouped_matmul(lhs, rhs, group_sizes):
-    """``lhs`` rows in groups of ``group_sizes`` (one more group than
-    ``rhs`` has: the rows no held expert takes, whose output is zero)
-    times their group's (k, n) matrix of ``rhs``."""
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-    return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, _gmm_tiling,
-                        None, None, False, _interpret())
+def _gmm(lhs, rhs, sizes, transpose_rhs: bool = False):
+    """``lhs`` rows in groups of ``sizes`` (one more group than ``rhs``
+    has: the rows no held expert takes, whose output is zero) times their
+    group's (k, n) matrix of ``rhs`` (or its transpose)."""
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend
+    return backend.gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiling, None, None,
+                       transpose_rhs=transpose_rhs, interpret=_interpret())
+
+
+def _tgmm(lhs, rhs, sizes, groups: int):
+    """Each of the first ``groups`` groups' ``lhs`` rows, transposed,
+    times its ``rhs`` rows: (groups, k, n)."""
+    from jax.experimental.pallas.ops.tpu.megablox.ops import backend
+    return backend.tgmm(lhs.swapaxes(0, 1), rhs, sizes, lhs.dtype,
+                        _gmm_tiling, None, groups, interpret=_interpret())
 
 
 def _relu2(h):
     return jnp.square(jax.nn.relu(h))
+
+
+def _dispatch_sizes(m: int) -> tuple[int, ...]:
+    """The buffer sizes a held-expert layer picks from: ``m``, ``m/4``,
+    ``m/16``, ``m/64``, each a whole number of grouped-matmul tiles, in
+    increasing order."""
+    sizes = {max(-(-m // DISPATCH_RATIO ** i // GMM_TILE_M), 1) * GMM_TILE_M
+             for i in range(DISPATCH_STEPS)}
+    return tuple(sorted(sizes))
+
+
+def _buffer(r: int, top_k: int, order, counts, gate):
+    """A buffer of ``r`` rows: the assignments it takes (the held ones
+    first, by expert), their tokens, the grouped matmul's group sizes
+    and each row's gate (zero past the held rows)."""
+    with scope("moe_route"):
+        rows = order[:r]
+        n = counts.sum()
+        sizes = jnp.concatenate([counts, (r - n)[None]])
+        wt = jnp.where(jnp.arange(r) < n, gate[rows], 0.0)
+    return rows, rows // top_k, sizes, wt
+
+
+def _expert_rows(r: int, top_k: int, xf, order, counts, gate, w_up, w_down):
+    """The held assignments through their experts in a buffer of ``r``
+    rows, each row's output added to its token times its gate: (T, d)."""
+    rows, token, sizes, wt = _buffer(r, top_k, order, counts, gate)
+    with scope("moe_route"):
+        xs = xf[token]                                          # (r,d)
+    with scope("moe"):
+        ys = _gmm(_relu2(_gmm(xs, w_up, sizes)), w_down, sizes)
+    with scope("moe_route"):
+        out = jnp.zeros(xf.shape, jnp.float32).at[token].add(
+            ys.astype(jnp.float32) * wt[:, None])
+    return out.astype(xf.dtype)
+
+
+def _expert_rows_bwd(r: int, top_k: int, xf, order, counts, gate, w_up,
+                     w_down, g):
+    """:func:`_expert_rows`' gradient for ``xf``, ``gate``, ``w_up`` and
+    ``w_down``.  The buffer's forward is made again from the inputs.  The
+    gate scales the down projection's input and its gradient, not the
+    buffer's cotangent, so that this is a plain gather of ``g`` and the
+    gate's gradient needs no ``ys``: autodiff would hold the gathered
+    cotangent, its gated copy and ``ys`` at once, each as large as the
+    buffer."""
+    f32 = jnp.float32
+    rows, token, sizes, wt = _buffer(r, top_k, order, counts, gate)
+    with scope("moe_route"):
+        xs = xf[token]
+        gr = g[token]                                           # (r,d)
+    with scope("moe"):
+        pre = _gmm(xs, w_up, sizes)
+        d_h = _gmm(gr, w_down, sizes, transpose_rhs=True).astype(f32)
+        relu = jax.nn.relu(pre.astype(f32))
+        d_wt = jnp.sum(d_h * relu * relu, -1)
+        d_w_down = _tgmm((relu * relu * wt[:, None]).astype(xs.dtype), gr,
+                         sizes, w_down.shape[0])
+        d_pre = (d_h * wt[:, None] * 2 * relu).astype(xs.dtype)
+        d_xs = _gmm(d_pre, w_up, sizes, transpose_rhs=True)
+        d_w_up = _tgmm(xs, d_pre, sizes, w_up.shape[0])
+    with scope("moe_route"):
+        d_xf = jnp.zeros_like(xf).at[token].add(d_xs)
+        d_gate = jnp.zeros_like(gate).at[rows].add(
+            jnp.where(jnp.arange(r) < counts.sum(), d_wt, 0.0))
+    return d_xf, d_gate, d_w_up, d_w_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(ladder, top_k, pick, xf, order, counts, gate, w_up,
+                  w_down):
+    """:func:`_expert_rows` in the buffer of ``ladder[pick]`` rows.  Its
+    backward saves only the inputs: under autodiff ``lax.switch`` would
+    save every branch's residuals, and the branch taken would write
+    zeros for the others' buffers."""
+    return jax.lax.switch(
+        pick, [functools.partial(_expert_rows, r, top_k) for r in ladder],
+        xf, order, counts, gate, w_up, w_down)
+
+
+def _held_experts_fwd(ladder, top_k, pick, *args):
+    return _held_experts(ladder, top_k, pick, *args), (pick, args)
+
+
+def _held_experts_bwd(ladder, top_k, res, g):
+    pick, args = res
+    d_xf, d_gate, d_w_up, d_w_down = jax.lax.switch(
+        pick, [functools.partial(_expert_rows_bwd, r, top_k)
+               for r in ladder], *args, g)
+    return None, d_xf, None, None, d_gate, d_w_up, d_w_down
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def held_experts_apply(params, x: jnp.ndarray, *, n_routed: int, top_k: int,
@@ -294,13 +402,18 @@ def held_experts_apply(params, x: jnp.ndarray, *, n_routed: int, top_k: int,
     fp32 (a float32 matmul at full precision): sigmoid scores, the top
     ``top_k`` of score plus a per-expert correction bias (zero here, and
     not a parameter), weights the picked scores over their sum times
-    ``routed_scale``.  Of these assignments
-    the ones to the ``held = (first, count)`` experts are sorted by
-    expert into a buffer of ``B*S*min(top_k, count)`` rows, as many as
-    the held experts can ever receive, so that none is dropped; the
-    grouped matmul computes only the held experts' rows.  The shared
-    expert sees every token.  Counters: the assignments that reached a
-    held expert, and the busiest held expert's over the mean.
+    ``routed_scale``.  Of these assignments the ones to the
+    ``held = (first, count)`` experts are sorted by expert to the front.
+    They go to a buffer whose size ``lax.switch`` picks on the device:
+    the smallest of :func:`_dispatch_sizes` (the most the held experts
+    can ever receive, and its quarter, sixteenth and sixty-fourth) that
+    takes every held assignment.  None is dropped, and the dispatch
+    gather, the grouped matmul's masks and the combine cost the rows
+    routed, not the worst case.  The grouped matmul computes only the
+    held experts' rows; the combine scatter-adds each buffer row,
+    weighted by its gate, to its token.  The shared expert sees every
+    token.  Counters: the assignments that reached a held expert, the
+    busiest held expert's over the mean, and the buffer rows picked.
     """
     b, s, d = x.shape
     t = b * s
@@ -308,7 +421,7 @@ def held_experts_apply(params, x: jnp.ndarray, *, n_routed: int, top_k: int,
     dtype = x.dtype
     f32 = jnp.float32
     xf = x.reshape(t, d)
-    m = -(-t * min(top_k, count) // GMM_TILE_M) * GMM_TILE_M
+    ladder = _dispatch_sizes(t * min(top_k, count))
 
     with scope("moe_route"):
         def _route(xin):
@@ -327,34 +440,23 @@ def held_experts_apply(params, x: jnp.ndarray, *, n_routed: int, top_k: int,
         mine = (local >= 0) & (local < count)
         local = jnp.where(mine, local, count)   # not held: sorted last
         order = jnp.argsort(local, stable=True)
-        if m > t * top_k:
-            order = jnp.pad(order, (0, m - t * top_k))
-        rows = order[:m]
+        if ladder[-1] > t * top_k:
+            order = jnp.pad(order, (0, ladder[-1] - t * top_k))
         counts = jnp.sum(jax.nn.one_hot(local, count, dtype=jnp.int32), 0)
-        sizes = jnp.concatenate([counts, m - counts.sum(keepdims=True)])
-        xs = xf[rows // top_k]                                  # (m,d)
-        # each assignment's row in the buffer; past the buffer: no row
-        pos = jnp.zeros((t * top_k,), jnp.int32).at[order[:t * top_k]] \
-            .set(jnp.arange(t * top_k, dtype=jnp.int32),
-                 unique_indices=True).reshape(t, top_k)
-        gate = jnp.where(mine.reshape(t, top_k), gate, 0.0)
+        n_held = counts.sum()
+        gate = gate.reshape(-1)                                 # (T·k,)
+        pick = jnp.sum(jnp.asarray(ladder[:-1]) < n_held)
 
     w_up = params["w_up"].astype(dtype).transpose(1, 0, 2)      # (E,d,f)
     w_down = params["w_down"].astype(dtype).transpose(1, 0, 2)  # (E,f,d)
-    h = _relu2(_grouped_matmul(xs, w_up, sizes))
-    ys = _grouped_matmul(h, w_down, sizes)                      # (m,d)
-
-    with scope("moe_route"):
-        out = jnp.zeros((t, d), f32)
-        for j in range(top_k):
-            yj = ys.at[pos[:, j]].get(mode="fill", fill_value=0)
-            out = out + yj.astype(f32) * gate[:, j:j + 1]
-        out = out.astype(dtype)
+    out = _held_experts(ladder, top_k, pick, xf, order, counts, gate, w_up,
+                        w_down)
     if "shared_up" in params:
         out = out + _relu2(xf @ params["shared_up"].astype(dtype)) \
             @ params["shared_down"].astype(dtype)
-    total = counts.sum().astype(f32)
+    total = n_held.astype(f32)
     stats = {"moe_held_assignments": total,
              "moe_held_load_max": counts.max().astype(f32) * count
-             / jnp.maximum(total, 1.0)}
+             / jnp.maximum(total, 1.0),
+             "moe_dispatch_rows": jnp.asarray(ladder, f32)[pick]}
     return out.reshape(b, s, d), stats

@@ -181,6 +181,7 @@ class Trainer:
         experts = ""
         if "moe_held_assignments" in m:
             experts = (f"held={m['moe_held_assignments']:.0f} "
+                       f"rows={m['moe_dispatch_rows']:.0f} "
                        f"load_max={m['moe_held_load_max']:.2f} ")
         print(f"[trainer] step {step+1} "
               f"loss={m['loss']:.4f} "

@@ -8,7 +8,9 @@ plain float32 reference ``bench/reference/nemotron_h.py`` at a tiny size.
 - the held-share expert layer: the shares of 2 or 4 parts, with the
   shared expert counted once, add up to the uncut reference's layer, and
   no assignment to a held expert is dropped when every token picks the
-  same experts;
+  same experts; routing that sends few or all assignments to the held
+  experts picks the smallest buffer that takes them, and the output and
+  its gradients are the reference's in each;
 - the full configuration counts 31.6 B parameters, and serving refuses
   the expert-only kind.
 """
@@ -210,8 +212,55 @@ def test_skewed_routing_drops_no_held_assignment():
                       for r in h])
     assert float(stats["moe_held_assignments"]) == 2 * 40 * top_k
     assert float(stats["moe_held_load_max"]) == pytest.approx(count / top_k)
+    # 320 held rows: the worst case itself, in whole 128-row tiles
+    assert float(stats["moe_dispatch_rows"]) == 384
     with jax.default_matmul_precision("highest"):
         assert _rel(y, want) < 1e-5
+
+
+@pytest.mark.parametrize("n_fav,rows", [(24, 128), (100, 512), (2048, 8192)])
+def test_held_buffer_follows_the_routing(n_fav, rows):
+    """2 x 1,024 tokens, top 4 of 16 experts, 4 held: the buffer holds
+    8,192 rows (every assignment held), 2,048, 512 or 128.  The first
+    ``n_fav`` tokens prefer the held experts and the rest the others;
+    the layer picks the smallest size that takes the held assignments,
+    and its output and gradients (input, router, both projections) are
+    the reference's."""
+    n_routed, top_k, d, first, count = 16, 4, 32, 0, 4
+    assert moe._dispatch_sizes(2048 * top_k) == (128, 512, 2048, 8192)
+    favour = jnp.zeros((d, n_routed)).at[0, count:].set(3.0) \
+        .at[1, :count].set(6.0)
+    p = _expert_layer(n_routed, top_k, d=d)
+    p = _share(dict(p, router=p["router"] + favour), first, count)
+    h = jnp.abs(jax.random.normal(jax.random.key(8), (2, 1024, d)))
+    fav = (jnp.arange(2048) < n_fav).reshape(2, 1024)
+    h = h.at[..., 0].set(jnp.where(fav, 0.0, 1.0 + h[..., 0]))
+    h = h.at[..., 1].set(jnp.where(fav, 1.0 + h[..., 1], 0.0))
+    cot = jax.random.normal(jax.random.key(9), h.shape)
+
+    def prog(p, h):
+        y, stats = moe.held_experts_apply(
+            p, h, n_routed=n_routed, top_k=top_k, held=(first, count),
+            routed_scale=2.5)
+        return jnp.sum(y * cot), (y, stats)
+
+    def want(p, h):
+        y = jnp.stack([ref._experts(r, p, top_k=top_k, first=first,
+                                    scale=2.5, fp8=False) for r in h])
+        return jnp.sum(y * cot), y
+
+    (_, (y, stats)), grads = jax.value_and_grad(
+        prog, argnums=(0, 1), has_aux=True)(p, h)
+    with jax.default_matmul_precision("highest"):
+        (_, y_want), g_want = jax.value_and_grad(
+            want, argnums=(0, 1), has_aux=True)(p, h)
+    held = float(stats["moe_held_assignments"])
+    assert rows // 4 < held <= rows
+    assert float(stats["moe_dispatch_rows"]) == rows
+    assert _rel(y, y_want) < 1e-5
+    assert _rel(grads[1], g_want[1]) < 1e-5
+    for k in ("router", "w_up", "w_down"):
+        assert _rel(grads[0][k], g_want[0][k]) < 1e-5, k
 
 
 def test_full_config_has_the_published_parameter_count():

@@ -153,6 +153,9 @@ def test_ssd_block_memory_at_published_widths(one_chip):
 # Nemotron 3 Nano's expert block and attention at published widths, one
 # chip's share: 2 x 8192 tokens, 8 of 128 experts held, GQA 32/2
 NEM_T, NEM_D, NEM_F, NEM_E, NEM_HELD = 2 * 8192, 2688, 1856, 128, 8
+# one held block's gradient with the buffer sized for every assignment
+# held (98,304 rows), whatever the routing
+NEM_BLOCK_TEMP_BYTES = 2_312_546_816
 
 
 def _kernel_lines(text: str) -> list:
@@ -162,9 +165,12 @@ def _kernel_lines(text: str) -> list:
 
 def test_held_expert_block_compiles_with_grouped_matmuls(one_chip,
                                                          monkeypatch):
-    """The held-share expert block's forward and backward compile for a
-    v5e with the megablox kernels (gmm forward and input gradient, tgmm
-    weight gradient) under ``moe``, and its routing under ``moe_route``."""
+    """The held-share expert block's forward and backward, remat'd as in
+    training, compile for a v5e with the megablox kernels (gmm forward
+    and input gradient, tgmm weight gradient) under ``moe``, and its
+    routing under ``moe_route``.  The buffer's size is picked on the
+    device (a conditional), and the temporaries stay within the
+    worst-case-sized buffer's (2,312,546,816 B) plus 5 %."""
     from jax.experimental.pallas.ops.tpu.megablox import common
     from repro.nn import moe, param
     from repro.obs import scope
@@ -185,13 +191,17 @@ def test_held_expert_block_compiles_with_grouped_matmuls(one_chip,
                                           routed_scale=2.5)
         return jnp.sum(y.astype(jnp.float32))
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
+    compiled = jax.jit(jax.grad(jax.checkpoint(loss), argnums=(0, 1))
+                       ).lower(params, x).compile()
+    text = compiled.as_text()
     kernels = _kernel_lines(text)
     names = {ln[1:].split(" ", 1)[0].rsplit(".", 1)[0] for ln in kernels}
     assert names == {"gmm", "tgmm"}, names
     assert all('repro_scope="moe"' in ln for ln in kernels)
     assert 'repro_scope="moe_route"' in text
+    assert " conditional(" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.05 * NEM_BLOCK_TEMP_BYTES, temp
 
 
 def test_splash_attention_compiles_at_8k(one_chip, monkeypatch):
